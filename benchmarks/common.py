@@ -1,9 +1,8 @@
 """Shared benchmark scaffolding.
 
 Paper-scale settings (m=20/100, 5 trials, LeNet-5 on EMNIST/CIFAR) are
-reproduced in *structure*; the default "fast" scale is sized for this
-1-core CPU container (documented in EXPERIMENTS.md). ``--full`` restores
-paper-scale m/rounds/trials.
+reproduced in *structure*; the default "fast" scale is sized for a
+1-core CPU host. ``--full`` restores paper-scale m/rounds/trials.
 """
 from __future__ import annotations
 
@@ -60,11 +59,12 @@ def make_params0(key, s: BenchScale, num_classes=None):
 
 
 def make_strategy(name: str, params0, s: BenchScale, *, chunk_size=None,
-                  mesh=None, w_refresh=None, async_buffer=None, faults=None,
-                  robust=None, transport=None, topology=None, selection=None,
-                  **kw):
+                  mesh=None, shard_state=False, w_refresh=None,
+                  async_buffer=None, faults=None, robust=None, transport=None,
+                  topology=None, selection=None, **kw):
     cfg = FedConfig(batch_size=s.batch_size, chunk_size=chunk_size, mesh=mesh,
-                    w_refresh=w_refresh, async_buffer=async_buffer,
+                    shard_state=shard_state, w_refresh=w_refresh,
+                    async_buffer=async_buffer,
                     faults=faults, robust=robust, transport=transport,
                     topology=topology, selection=selection)
     if name == "ucfl":
@@ -85,7 +85,8 @@ def make_strategy(name: str, params0, s: BenchScale, *, chunk_size=None,
 
         base = inspect.signature(REGISTRY[name]).parameters["cfg"].default
         cfg = dataclasses.replace(
-            base, chunk_size=chunk_size, mesh=mesh, w_refresh=w_refresh,
+            base, chunk_size=chunk_size, mesh=mesh, shard_state=shard_state,
+            w_refresh=w_refresh,
             async_buffer=async_buffer, faults=faults, robust=robust,
             transport=transport, topology=topology, selection=selection)
         return REGISTRY[name](lenet.apply, params0, cfg, **kw)

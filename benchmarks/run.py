@@ -26,6 +26,9 @@ def main() -> None:
     ap.add_argument("--only", default="all")
     args = ap.parse_args()
 
+    from repro import compile_cache
+
+    compile_cache.configure()
     from benchmarks import (common, fig4_silhouette, fig5_comm_efficiency,
                             fig6_parallel_ucfl, fig7_minibatch, kernel_bench,
                             participation_sweep, roofline_report,

@@ -342,15 +342,14 @@ def staleness_update(stale, idx, mask):
 
 
 def cohort_gather(full, safe, *, impl=None):
-    """Round-start cohort gather ``full[safe]`` as ONE kernel launch.
+    """Round-start cohort gather ``full[safe]``.
 
-    A single-leaf stacked tree gathers through the HBM-resident per-row
-    DMA kernel (:func:`repro.kernels.ops.cohort_gather`) on the
-    zero-copy (m, d) flat view — ``full`` never streams through VMEM, so
-    traffic is O(c·d) at any m. Multi-leaf trees fall back to the
+    A single-leaf stacked tree gathers through the HBM-resident row
+    loop (:func:`repro.kernels.ops.cohort_gather`) on the zero-copy
+    (m, d) flat view — ``full`` never streams through VMEM, so traffic
+    is O(c·d) at any m. Multi-leaf trees fall back to the
     per-leaf ``jnp.take`` (:func:`repro.core.pytree.gather_rows`) —
-    XLA's gather is already O(c·d) there and raveling the full state
-    would cost the copy this path avoids. ``safe`` must be pre-clamped
+    raveling the full state would copy it. ``safe`` must be pre-clamped
     (:func:`safe_gather_index`); semantics are bit-identical to
     ``gather_rows``.
     """

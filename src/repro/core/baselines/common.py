@@ -182,7 +182,7 @@ class StateOps:
         """Cohort gather ``tree[safe]`` (``safe`` pre-clamped).
 
         Replicated single-leaf states route through the HBM-resident
-        per-row DMA gather kernel (:func:`aggregation.cohort_gather` —
+        row gather (:func:`aggregation.cohort_gather` —
         bit-identical to ``gather_rows``, O(c·d) traffic at any m)."""
         if self.sharded:
             return mesh_lib.shard_gather_rows(tree, safe, self.mesh)
@@ -197,12 +197,15 @@ class StateOps:
 
     # ---- fused PS mixes
 
+    def _replicated(self, fn):
+        # replicated layout on a mesh: the PS kernels run per device
+        # inside a shard_map (Mosaic kernels cannot be auto-partitioned)
+        return fn if self.mesh is None else mesh_lib.replicated(fn,
+                                                                self.mesh)
+
     def mix_scatter(self, full, cohort_updated, rows, idx, mask, *,
                     impl=None):
         """:func:`repro.core.aggregation.mix_scatter` in either layout."""
-        if not self.sharded:
-            return aggregation.mix_scatter(full, cohort_updated, rows,
-                                           idx, mask, impl=impl)
         return self.mix_scatter_flat(full, stacked_ravel(cohort_updated),
                                      rows, idx, mask, impl=impl)
 
@@ -218,8 +221,9 @@ class StateOps:
         INSIDE the same shard_map — the flush's one model-sized
         collective."""
         if not self.sharded:
-            return aggregation.mix_scatter_flat(full, flat_c, rows, idx,
-                                                mask, impl=impl)
+            return self._replicated(functools.partial(
+                aggregation.mix_scatter_flat, impl=impl))(full, flat_c,
+                                                          rows, idx, mask)
         update = mesh_lib.shard_block_update(
             lambda block, loc, lm, fc, w: aggregation.mix_scatter_flat(
                 block, fc, w, loc, lm, impl=impl),
@@ -230,8 +234,9 @@ class StateOps:
         """:func:`fedavg_masked_mix` in either layout (the (1, c) mix is
         replicated; sharded states broadcast it block-wise)."""
         if not self.sharded:
-            return fedavg_masked_mix(params, updated, idx, mask, n,
-                                     impl=impl)
+            return self._replicated(functools.partial(
+                fedavg_masked_mix, impl=impl))(params, updated, idx, mask,
+                                               n)
         safe = aggregation.safe_gather_index(idx, n.shape[0])
         w = aggregation.masked_fedavg_weights(jnp.take(n, safe), mask)
         mixed = aggregation.user_centric(updated, w, impl=impl)

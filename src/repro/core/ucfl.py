@@ -62,6 +62,7 @@ from repro.data.loader import fixed_partition
 from repro.federated import async_buffer
 from repro.federated import client as fedclient
 from repro.federated import faults as faults_lib
+from repro.federated import mesh as mesh_lib
 from repro.federated import topology as topology_lib
 from repro.federated import transport as transport_lib
 from repro.kernels import ops
@@ -91,7 +92,11 @@ def compute_collaboration(apply_fn, params0, data, *, var_batch_size=100,
 
     run = fedclient.client_vmap(one_client, chunk_size=chunk_size, mesh=mesh)
     full, sig = run(data.x, data.y)
-    delta = similarity.pairwise_delta(full, impl=impl)
+    delta_fn = functools.partial(similarity.pairwise_delta, impl=impl)
+    mesh = mesh_lib.resolve(mesh)
+    if mesh is not None:
+        delta_fn = mesh_lib.replicated(delta_fn, mesh)
+    delta = delta_fn(full)
     w = similarity.mixing_weights(delta, sig, data.n.astype(jnp.float32))
     return {"full_grads": full, "sigma_sq": sig, "delta": delta, "W": w}
 
@@ -192,7 +197,11 @@ def make_ucfl(apply_fn, params0, cfg: FedConfig = FedConfig(), *,
             kkey = silhouette_key if silhouette_key is not None else key
             k, _ = clustering.choose_num_streams(kkey, w, impl=kernel_impl)
         if k is not None:
-            res = clustering.kmeans(key, w, int(k), impl=kernel_impl)
+            kmeans = functools.partial(clustering.kmeans, k=int(k),
+                                       impl=kernel_impl)
+            if sops.mesh is not None:
+                kmeans = mesh_lib.replicated(kmeans, sops.mesh)
+            res = kmeans(key, w)
             labels = res.labels
             # cluster-membership one-hots: lets the cohort round count the
             # represented clusters (downlink streams) on device instead of

@@ -42,8 +42,6 @@ code path on every PR (the ``multi-device`` job).
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -53,18 +51,10 @@ from repro.federated import participation
 
 AXIS = "clients"
 
-if hasattr(jax, "shard_map"):  # jax >= 0.6 top-level API
-    _shard_map = jax.shard_map
-else:  # jax 0.4/0.5: experimental API
-    from jax.experimental.shard_map import shard_map as _shard_map
-# The all-gathered outputs are replicated, but the static replication
-# checker cannot infer that through lax.all_gather — disable it. The
-# kwarg was renamed check_rep -> check_vma independently of the API's
-# promotion to jax.shard_map, so pick the spelling off the actual
-# signature rather than the module location.
-_RELAX = {("check_vma" if "check_vma"
-           in inspect.signature(_shard_map).parameters
-           else "check_rep"): False}
+
+# Every shard_map below passes check_vma=False: the all-gathered outputs
+# are replicated, but the static replication checker cannot infer that
+# through lax.all_gather.
 
 
 def client_mesh(num_shards: int | None = None, *, devices=None):
@@ -117,6 +107,21 @@ def slot_sharding(mesh) -> NamedSharding:
 def replicated_sharding(mesh) -> NamedSharding:
     """Sharding of the (m, d) stacked state and the (c, c) mix rules."""
     return NamedSharding(mesh, P())
+
+
+def replicated(fn, mesh):
+    """Run ``fn`` whole on every device of ``mesh``, each on its own
+    replicated copy of every argument.
+
+    A Pallas TPU (Mosaic) kernel cannot be partitioned automatically:
+    lowered in a jit that spans several devices it raises unless it sits
+    inside a shard_map. Server-side math of the replicated layout (the
+    fused mix-scatter, the Eq. 9 Gram matrix, K-means) goes through here,
+    so each device runs the kernel on its full copy — what the replicated
+    layout computes anyway.
+    """
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)
 
 
 def pad_to_shards(slots: int, shards: int) -> int:
@@ -248,8 +253,8 @@ def shard_gather_rows(tree, safe, mesh):
                 jnp.take(b, loc, axis=0), 0), block)
         return jax.tree.map(lambda x: jax.lax.psum(x, axis), part)
 
-    return _shard_map(body, mesh=mesh, in_specs=(P(axis), P()),
-                      out_specs=P(), **_RELAX)(tree, safe)
+    return jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P()),
+                         out_specs=P(), check_vma=False)(tree, safe)
 
 
 def shard_scatter_rows(tree, idx, updates, mesh):
@@ -266,8 +271,9 @@ def shard_scatter_rows(tree, idx, updates, mesh):
             lambda b, u: b.at[loc].set(u.astype(b.dtype), mode="drop"),
             block, updates)
 
-    return _shard_map(body, mesh=mesh, in_specs=(P(axis), P(), P()),
-                      out_specs=P(axis), **_RELAX)(tree, idx, updates)
+    return jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P(), P()),
+                         out_specs=P(axis), check_vma=False)(tree, idx,
+                                                             updates)
 
 
 def shard_block_update(fn, mesh, *, gather_args=0):
@@ -298,10 +304,10 @@ def shard_block_update(fn, mesh, *, gather_args=0):
 
         specs = tuple(P(axis) if i < gather_args else P()
                       for i in range(len(args)))
-        return _shard_map(body, mesh=mesh,
-                          in_specs=(P(axis), P(), P()) + specs,
-                          out_specs=P(axis), **_RELAX)(tree, idx, mask,
-                                                       *args)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(P(axis), P(), P()) + specs,
+                             out_specs=P(axis), check_vma=False)(
+                                 tree, idx, mask, *args)
 
     return update
 
@@ -318,17 +324,18 @@ def shard_broadcast_rows(full, mixed, alive, mesh):
                 alive, jnp.broadcast_to(x, (p.shape[0],) + x.shape[1:]), p),
             mixed, block)
 
-    return _shard_map(body, mesh=mesh, in_specs=(P(axis), P(), P()),
-                      out_specs=P(axis), **_RELAX)(full, mixed, alive)
+    return jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P(), P()),
+                         out_specs=P(axis), check_vma=False)(full, mixed,
+                                                             alive)
 
 
 def all_gather_rows(x, mesh):
     """Replicate a row-sharded array: tiled all_gather over the leading
     axis (the buffered-async flush's one model-sized collective)."""
     axis = _axis(mesh)
-    return _shard_map(
+    return jax.shard_map(
         lambda b: jax.lax.all_gather(b, axis, axis=0, tiled=True),
-        mesh=mesh, in_specs=P(axis), out_specs=P(), **_RELAX)(x)
+        mesh=mesh, in_specs=P(axis), out_specs=P(), check_vma=False)(x)
 
 
 def shard_clients(fn, mesh):
@@ -353,5 +360,5 @@ def shard_clients(fn, mesh):
         return jax.tree.map(
             lambda x: jax.lax.all_gather(x, axis, axis=0, tiled=True), out)
 
-    return _shard_map(body, mesh=mesh, in_specs=P(axis), out_specs=P(),
-                      **_RELAX)
+    return jax.shard_map(body, mesh=mesh, in_specs=P(axis), out_specs=P(),
+                         check_vma=False)

@@ -60,6 +60,7 @@ class History:
     ``wall_s`` is the steady-state ROUND time only (warm-up/compilation
     excluded by the warm-up call, evaluation excluded by construction);
     ``eval_s`` holds the accumulated evaluation time separately.
+    ``state`` is the strategy state after the last round.
     """
 
     strategy: str
@@ -69,6 +70,7 @@ class History:
     metrics: List[Dict[str, Any]]
     wall_s: float = 0.0
     eval_s: float = 0.0
+    state: Any = None
 
     @property
     def final_avg(self):
@@ -175,6 +177,9 @@ def run(strategy, apply_fn, data, key, *, rounds: int, eval_every: int = 1,
             donation_safe_copy(state), data,
             jax.random.fold_in(key, 0x5EED), wcohort)
         jax.block_until_ready(wstate)
+        if check_finite:  # the eval-round finite check runs on the clock
+            jax.block_until_ready(
+                _client_rows_finite(strategy.eval_params(wstate)))
         del wstate
 
     t0 = time.time()
@@ -219,6 +224,7 @@ def run(strategy, apply_fn, data, key, *, rounds: int, eval_every: int = 1,
         if rnd % eval_every == 0 or rnd == rounds:
             do_eval(rnd, metrics)
     hist.wall_s = time.time() - t0 - hist.eval_s
+    hist.state = state
     return hist
 
 

@@ -23,15 +23,13 @@ Traffic honesty — the two regimes:
     is ~(2·m + c)·d floats per call; the fusion saves the extra
     mix-output allocation, the per-leaf launch overhead, and the
     separate XLA scatter pass — not the state read. ``block_d`` is
-    clamped so the two (m_pad, BLOCK_D) slabs plus the theta tile fit
-    the ~16 MB VMEM budget (:data:`_VMEM_BUDGET_FLOATS`), which bounds
-    single-call m: once ``2·m_pad + 2·c_pad`` rows can't sustain even a
-    128-wide block (m_pad ≈ 12k rows), the slab is infeasible.
-  * **HBM-resident** (:mod:`repro.kernels.masked_gather_mix_scatter`).
-    ``full`` stays in ``pltpu.ANY``/HBM and per-slot async DMA moves
-    only the c cohort rows — traffic O(c·d) at any m, no m-dependent
-    VMEM bound, and no d padding at all (the tail tile re-covers the
-    last columns at an unaligned offset).
+    clamped so every buffer the pipeline allocates (:func:`_slab_cap`)
+    fits the compiler's scoped VMEM limit, which bounds single-call m:
+    once ``4·m_pad + 3·c_pad`` rows can't sustain even a 128-wide block
+    (m_pad ≈ 6k rows at c=64), the slab is infeasible.
+  * **HBM-resident** (:mod:`repro.kernels.cohort_rows`). ``full`` stays
+    in HBM and XLA moves only the c cohort rows — traffic O(c·d) at any
+    m, no m-dependent VMEM bound, and no padding at all.
 
 :func:`slab_fits` is the boundary between the regimes;
 :func:`repro.kernels.ops.masked_mix_scatter` auto-dispatches on it
@@ -65,13 +63,23 @@ def _round_up(x: int, mult: int) -> int:
     return (x + mult - 1) // mult * mult
 
 
-# keep the two (m_pad, block) slabs + (c_pad, block) theta tile + mix well
-# inside the ~16 MB/core VMEM budget
-_VMEM_BUDGET_FLOATS = 3 * 1 << 20
+# Everything the pipeline allocates must fit the compiler's default
+# scoped VMEM limit (16 MiB on v5e); this budget keeps 4 MiB of it free.
+_VMEM_BUDGET_FLOATS = 3 << 20
+
+
+def _slab_cap(m_pad: int, c_pad: int) -> int:
+    """Widest block (in columns) whose buffers fit the VMEM budget. The
+    pipeline double-buffers every blocked operand: the full-state input
+    and the output (4 (m_pad, block) slabs), theta (2 (c_pad, block)
+    tiles) and W (2 (c_pad, c_pad)); the mix scratch adds one more
+    (c_pad, block) tile."""
+    return ((_VMEM_BUDGET_FLOATS - 2 * c_pad * c_pad)
+            // (4 * m_pad + 3 * c_pad))
 
 
 def _pick_block_d(block_d: int, d: int, m_pad: int, c_pad: int) -> int:
-    cap = max(_VMEM_BUDGET_FLOATS // (2 * m_pad + 2 * c_pad), 128)
+    cap = max(_slab_cap(m_pad, c_pad), 128)
     block_d = max(min(block_d, cap) // 128 * 128, 128)
     if d % 128 == 0:
         # pick a divisor of d so the d axis needs no padding at all
@@ -81,14 +89,11 @@ def _pick_block_d(block_d: int, d: int, m_pad: int, c_pad: int) -> int:
 
 
 def slab_fits(m: int, c: int) -> bool:
-    """True when the VMEM-slab formulation is feasible for (m, c): the two
-    (m_pad, block) state slabs plus the (c_pad, block) theta/mix tiles
-    must sustain at least a 128-wide block inside the VMEM budget. Past
-    this bound (m_pad ≈ 12k rows) :mod:`repro.kernels.ops` auto-selects
-    the HBM-resident kernel."""
-    m_pad = _round_up(int(m), 8)
-    c_pad = _round_up(int(c), 8)
-    return _VMEM_BUDGET_FLOATS // (2 * m_pad + 2 * c_pad) >= 128
+    """True when the VMEM-slab formulation is feasible for (m, c): its
+    buffers (:func:`_slab_cap`) must sustain at least a 128-wide block
+    inside the VMEM budget. Past this bound (m_pad ≈ 6k rows at c=64)
+    :mod:`repro.kernels.ops` auto-selects the HBM-resident row path."""
+    return _slab_cap(_round_up(int(m), 8), _round_up(int(c), 8)) >= 128
 
 
 def padding_copy_needed(m: int, c: int, d: int,
@@ -106,21 +111,24 @@ def padding_copy_needed(m: int, c: int, d: int,
     return (m_pad, d_pad) != (int(m), int(d))
 
 
-def _kernel(idx_ref, mask_ref, w_ref, theta_ref, full_ref, out_ref, *, c, m):
+def _kernel(idx_ref, mask_ref, w_ref, theta_ref, full_ref, out_ref, mix_ref,
+            *, c, m):
     # Copy-through of the untouched rows (a no-op self-copy when the
     # output buffer aliases ``full``), then overwrite the cohort rows.
     out_ref[...] = full_ref[...]
-    mix = jnp.dot(
+    # the mix goes through a VMEM scratch ref: Mosaic lowers a dynamic
+    # one-row slice of a ref, not of a value
+    mix_ref[...] = jnp.dot(
         w_ref[...].astype(jnp.float32), theta_ref[...].astype(jnp.float32),
         preferred_element_type=jnp.float32,
-    ).astype(out_ref.dtype)
+    ).astype(mix_ref.dtype)
 
     def body(i, carry):
         r = idx_ref[i]
 
         @pl.when((mask_ref[i] != 0) & (r < m))
         def _():
-            out_ref[pl.ds(r, 1), :] = jax.lax.dynamic_slice_in_dim(mix, i, 1, 0)
+            out_ref[pl.ds(r, 1), :] = mix_ref[pl.ds(i, 1), :]
 
         return carry
 
@@ -179,6 +187,7 @@ def masked_mix_scatter_pallas(w, theta, idx, mask, full, *,
             pl.BlockSpec((m_pad, block_d), lambda j, *_: (0, j)),
         ],
         out_specs=pl.BlockSpec((m_pad, block_d), lambda j, *_: (0, j)),
+        scratch_shapes=[pltpu.VMEM((c_pad, block_d), full.dtype)],
     )
     out = pl.pallas_call(
         functools.partial(_kernel, c=c, m=m),

@@ -8,13 +8,13 @@ Every op picks an implementation:
     (CI uses it to force interpret mode on CPU), else pallas on TPU and
     ref elsewhere.
 
-The cohort gather/scatter ops additionally pick a *variant*: the VMEM
-slab kernel or the HBM-resident DMA kernel
-(:mod:`repro.kernels.masked_gather_mix_scatter`). Auto picks the slab
-while it fits the VMEM budget (``masked_mix_scatter.slab_fits``) and
-falls over to HBM-resident past it; the suffixes ``_slab`` / ``_hbm``
-(e.g. ``impl="interpret_hbm"`` or ``REPRO_KERNEL_IMPL=pallas_hbm``)
-force either side.
+The cohort mix-scatter additionally picks a *variant*: the VMEM slab
+kernel or the HBM-resident row path (:mod:`repro.kernels.cohort_rows`,
+plain XLA that moves only the cohort's rows). Auto picks the slab while
+it fits the VMEM budget (``masked_mix_scatter.slab_fits``) and falls over
+to HBM-resident past it; the suffixes ``_slab`` / ``_hbm`` (e.g.
+``impl="interpret_hbm"`` or ``REPRO_KERNEL_IMPL=pallas_hbm``) force
+either side.
 """
 from __future__ import annotations
 
@@ -23,12 +23,9 @@ import os
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ref
+from repro.kernels import cohort_rows, ref
 from repro.kernels.masked_mix_scatter import (
     masked_mix_scatter_pallas, slab_fits,
-)
-from repro.kernels.masked_gather_mix_scatter import (
-    cohort_gather_pallas, masked_gather_mix_scatter_pallas,
 )
 from repro.kernels.mix_aggregate import mix_aggregate_pallas
 from repro.kernels.pairwise_delta import gram_pallas
@@ -85,8 +82,8 @@ def masked_mix_scatter(w, theta, idx, mask, full, *, impl=None, block_d=None):
     in place — callers must not reuse the input buffer afterwards.
 
     Variant selection (``_slab``/``_hbm`` impl suffix, else auto): the
-    VMEM-slab kernel while ``slab_fits(m, c)``, the HBM-resident DMA
-    kernel past that bound — O(c·d) traffic at any m.
+    VMEM-slab kernel while ``slab_fits(m, c)``, the HBM-resident row
+    path past that bound — O(c·d) traffic at any m.
     """
     if theta.shape[1] != full.shape[1]:
         raise ValueError(
@@ -99,26 +96,27 @@ def masked_mix_scatter(w, theta, idx, mask, full, *, impl=None, block_d=None):
     impl, variant = _split_variant(impl)
     if variant is None:
         variant = "slab" if slab_fits(full.shape[0], w.shape[0]) else "hbm"
+    if variant == "hbm":
+        return cohort_rows.mix_scatter_rows(w, theta, idx, mask, full)
     kwargs = {} if block_d is None else {"block_d": block_d}
-    kernel = (masked_gather_mix_scatter_pallas if variant == "hbm"
-              else masked_mix_scatter_pallas)
-    return kernel(w, theta, idx, mask, full,
-                  interpret=(impl == "interpret"), **kwargs)
+    return masked_mix_scatter_pallas(w, theta, idx, mask, full,
+                                     interpret=(impl == "interpret"),
+                                     **kwargs)
 
 
 def cohort_gather(full, idx, *, impl=None):
     """Round-start cohort gather: ``out[i] = full[min(idx[i], m-1)]``.
 
-    The pallas path is the HBM-resident per-row DMA kernel
-    (:func:`repro.kernels.masked_gather_mix_scatter.cohort_gather_pallas`)
-    — ``full`` never leaves HBM, traffic O(c·d). ref is ``jnp.take`` on
-    the clamped indices (bit-identical semantics).
+    Every impl but ref takes the HBM-resident row loop
+    (:func:`repro.kernels.cohort_rows.gather_rows`) — traffic O(c·d) at
+    any m. ref is ``jnp.take`` on the clamped indices, which XLA's TPU
+    gather lowers with a temporary the size of ``full``; the two are
+    bit-identical.
     """
-    impl = _auto_impl(impl)
-    impl, _ = _split_variant(impl)
+    impl, _ = _split_variant(_auto_impl(impl))
     if impl == "ref":
         return ref.cohort_gather(full, idx)
-    return cohort_gather_pallas(full, idx, interpret=(impl == "interpret"))
+    return cohort_rows.gather_rows(full, idx)
 
 
 def pairwise_delta(g, *, impl=None, block_d=None):

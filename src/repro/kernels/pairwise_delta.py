@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.masked_mix_scatter import _VMEM_BUDGET_FLOATS
+
 
 DEFAULT_BLOCK_D = 4096
 
@@ -42,7 +44,11 @@ def gram_pallas(g, *, block_d: int = DEFAULT_BLOCK_D, interpret: bool = False):
     """Streaming Gram matrix of (m, d) -> (m, m) f32."""
     m, d = g.shape
     m_pad = _round_up(m, 8)
-    block_d = max(_round_up(min(block_d, _round_up(d, 128)), 128), 128)
+    # the pipeline double-buffers the (m_pad, block) input tile and the
+    # (m_pad, m_pad) accumulator; cap the block so both fit the budget
+    cap = (_VMEM_BUDGET_FLOATS - 2 * m_pad * m_pad) // (2 * m_pad)
+    block_d = min(block_d, _round_up(d, 128), cap) // 128 * 128
+    block_d = max(block_d, 128)
     d_pad = _round_up(d, block_d)
     g_p = jnp.zeros((m_pad, d_pad), g.dtype).at[:m, :d].set(g)
 

@@ -23,7 +23,7 @@ def mix_aggregate(w, theta):
 
 
 def cohort_gather(full, idx):
-    """Gather cohort rows (oracle for the HBM-resident DMA gather).
+    """Gather cohort rows (oracle for the HBM-resident row gather).
 
     ``out[i] = full[min(idx[i], m - 1)]`` — pad slots (sentinel index
     >= m) read the clamped last row, exactly the ``safe_gather_index``

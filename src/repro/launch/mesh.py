@@ -13,12 +13,13 @@ Mesh semantics (DESIGN.md §2):
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(*, data: int = 1, model: int = 1):
@@ -26,7 +27,8 @@ def make_host_mesh(*, data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = max(min(model, n // data), 1)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def client_axes(mesh) -> tuple:

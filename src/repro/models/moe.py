@@ -232,13 +232,7 @@ def apply_expert_parallel(p, x, cfg: MoEConfig, *, cf2: float = 1.5):
         y = jnp.zeros((nn, d_), jnp.float32).at[st].add(contrib * ws)
         return y.reshape(b_loc, s_, d_).astype(xs.dtype), aux
 
-    if hasattr(jax, "shard_map"):  # jax >= 0.6
-        smap = jax.shard_map
-        relax = {"check_vma": False}
-    else:  # jax 0.4/0.5: experimental API, `check_rep` spelling
-        from jax.experimental.shard_map import shard_map as smap
-        relax = {"check_rep": False}
-    y, aux = smap(
+    y, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(
@@ -249,7 +243,7 @@ def apply_expert_parallel(p, x, cfg: MoEConfig, *, cf2: float = 1.5):
             P(dp, None, None),  # x batch-sharded
         ),
         out_specs=(P(dp, None, None), P()),
-        **relax,
+        check_vma=False,
     )(p["router"], p["w_gate"], p["w_up"], p["w_down"], x)
     return y, aux
 

@@ -1,43 +1,14 @@
-"""Optional-hypothesis shim for the property-test modules.
-
-The offline CPU container does not ship ``hypothesis``; importing it at
-module scope used to abort collection of *every* test in the file. This
-shim degrades each ``@given(...)`` test to a precise skip when hypothesis
-is unavailable while leaving the plain parametrized tests runnable.
-"""
+"""Shared hypothesis set-up for the property-test modules."""
 from __future__ import annotations
 
-import pytest
+import hypothesis
+import hypothesis.strategies as st  # noqa: F401  (re-exported)
 
-try:
-    import hypothesis
-    import hypothesis.strategies as st
-
-    HAVE_HYPOTHESIS = True
-    given = hypothesis.given
-except ImportError:  # pragma: no cover - depends on environment
-    hypothesis = None
-    HAVE_HYPOTHESIS = False
-
-    class _LazyStrategies:
-        """Stands in for ``hypothesis.strategies`` inside @given(...) args."""
-
-        def __getattr__(self, name):
-            return lambda *a, **k: None
-
-    st = _LazyStrategies()
-
-    def given(*_args, **_kwargs):
-        return pytest.mark.skip(
-            reason="hypothesis not installed in this environment "
-                   "(offline container); property test skipped"
-        )
+given = hypothesis.given
 
 
 def load_ci_profile(*, max_examples: int, suppress_too_slow: bool = False):
-    """Register/load the deterministic CI profile (no-op without hypothesis)."""
-    if not HAVE_HYPOTHESIS:
-        return
+    """Register and load the deterministic CI profile."""
     kw = dict(deadline=None, max_examples=max_examples)
     if suppress_too_slow:
         kw["suppress_health_check"] = [hypothesis.HealthCheck.too_slow]

@@ -26,7 +26,8 @@ SCRIPT = textwrap.dedent("""\
 
     arch, kind = "%s", "%s"
     cfg = configs.get(arch).reduced()
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     shape = InputShape("t", 64, 8, kind)
     compiled, meta = dryrun.lower_one(cfg, shape, mesh, agg="user_centric")
     roof = roofline.analyze(compiled, cfg, shape, mesh_name="test",
@@ -49,7 +50,7 @@ SCRIPT = textwrap.dedent("""\
     ("whisper-large-v3", "prefill"),
 ])
 def test_small_mesh_lower_compile(arch, kind):
-    env = dict(os.environ, PYTHONPATH=SRC)
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, "-c", SCRIPT % (arch, kind)],
         capture_output=True, text=True, env=env, timeout=600,

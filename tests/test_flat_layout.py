@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from hypothesis_compat import HAVE_HYPOTHESIS, given, load_ci_profile, st
+from hypothesis_compat import given, load_ci_profile, st
 from repro.core import flat
 from repro.kernels import ops
 
@@ -133,8 +133,3 @@ def test_roundtrip_property(shapes, lead):
     for a, b in zip(jax.tree.leaves(stacked),
                     jax.tree.leaves(layout.unravel(mat))):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_hypothesis_marker():
-    # keeps the skip reason visible in -rs output when hypothesis is absent
-    assert HAVE_HYPOTHESIS in (True, False)

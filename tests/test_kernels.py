@@ -1,5 +1,7 @@
 """Per-kernel validation: Pallas (interpret mode) vs pure-jnp oracles,
 swept over shapes and dtypes, plus hypothesis property tests."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -139,7 +141,7 @@ def test_mix_scatter_all_pad_cohort_is_identity(variant):
 
 
 def test_hbm_mix_scatter_untouched_rows_identical():
-    """The HBM kernel never DMAs a non-cohort row — bit-identical."""
+    """The HBM row path never writes a non-cohort row — bit-identical."""
     rng = np.random.default_rng(1)
     m, c, d = 16, 5, 300
     w, theta, idx, mask, full, real = _scatter_case(m, c, d, 1, rng)
@@ -154,7 +156,7 @@ def test_hbm_mix_scatter_untouched_rows_identical():
 
 @pytest.mark.parametrize("m,c,d", [(8, 3, 128), (9, 4, 513), (4, 6, 300)])
 def test_cohort_gather_matches_take(m, c, d):
-    """The per-row DMA gather is bit-identical to clamped jnp.take,
+    """The row-loop gather is bit-identical to clamped jnp.take,
     including pad sentinels (>= m) and duplicate indices."""
     rng = np.random.default_rng(m + c)
     full = jnp.asarray(rng.normal(size=(m, d)).astype(np.float32))
@@ -169,10 +171,9 @@ def test_cohort_gather_matches_take(m, c, d):
 
 
 def test_kernel_shape_contracts_raise():
-    """Both scatter kernels reject malformed shapes with ValueError (not
+    """Both scatter variants reject malformed shapes with ValueError (not
     assert — the contract must survive python -O)."""
-    from repro.kernels.masked_gather_mix_scatter import (
-        cohort_gather_pallas, masked_gather_mix_scatter_pallas)
+    from repro.kernels.cohort_rows import gather_rows, mix_scatter_rows
     from repro.kernels.masked_mix_scatter import masked_mix_scatter_pallas
 
     w = jnp.zeros((3, 3))
@@ -180,25 +181,21 @@ def test_kernel_shape_contracts_raise():
     full = jnp.zeros((8, 16))
     idx = jnp.zeros((3,), jnp.int32)
     mask = jnp.ones((3,), bool)
-    for kernel in (masked_mix_scatter_pallas,
-                   masked_gather_mix_scatter_pallas):
+    for kernel in (functools.partial(masked_mix_scatter_pallas,
+                                     interpret=True), mix_scatter_rows):
         with pytest.raises(ValueError):
-            kernel(jnp.zeros((3, 2)), theta, idx, mask, jnp.array(full),
-                   interpret=True)
+            kernel(jnp.zeros((3, 2)), theta, idx, mask, jnp.array(full))
         with pytest.raises(ValueError):
-            kernel(w, jnp.zeros((3, 8)), idx, mask, jnp.array(full),
-                   interpret=True)
+            kernel(w, jnp.zeros((3, 8)), idx, mask, jnp.array(full))
         with pytest.raises(ValueError):
             kernel(w, theta, jnp.zeros((4,), jnp.int32), mask,
-                   jnp.array(full), interpret=True)
+                   jnp.array(full))
         with pytest.raises(ValueError):
-            kernel(w, theta, idx, mask, jnp.zeros((8, 16, 1)),
-                   interpret=True)
+            kernel(w, theta, idx, mask, jnp.zeros((8, 16, 1)))
     with pytest.raises(ValueError):
-        cohort_gather_pallas(jnp.zeros((8,)), idx, interpret=True)
+        gather_rows(jnp.zeros((8,)), idx)
     with pytest.raises(ValueError):
-        cohort_gather_pallas(full, jnp.zeros((3, 1), jnp.int32),
-                             interpret=True)
+        gather_rows(full, jnp.zeros((3, 1), jnp.int32))
 
 
 def test_aligned_dim_and_zero_copy_bound():

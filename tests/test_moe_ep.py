@@ -14,7 +14,8 @@ SCRIPT = textwrap.dedent("""\
     import jax, jax.numpy as jnp
     from repro.models import moe
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = moe.MoEConfig(d_model=32, d_ff=64, num_experts=8, top_k=2,
                         capacity_factor=8.0, ep_axis="data")
     p = moe.init(jax.random.PRNGKey(0), cfg)
@@ -34,7 +35,7 @@ SCRIPT = textwrap.dedent("""\
 
 
 def test_expert_parallel_matches_dense_oracle():
-    env = dict(os.environ, PYTHONPATH=SRC)
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", SCRIPT],
                          capture_output=True, text=True, env=env,
                          timeout=600)

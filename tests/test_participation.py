@@ -20,7 +20,7 @@ from repro.federated import simulation
 from repro.federated.participation import (Cohort, ParticipationConfig,
                                            as_cohort, pad_slots,
                                            sample_cohort)
-from tests.hypothesis_compat import HAVE_HYPOTHESIS, given, st  # noqa: F401
+from tests.hypothesis_compat import given, st
 from repro.models import lenet
 
 
@@ -386,6 +386,43 @@ def test_wall_s_excludes_eval_time(monkeypatch):
     assert h.eval_s >= 0.55            # three stubbed eval passes
     assert h.wall_s < h.eval_s / 2     # rounds are trivial next to them
     assert len(h.avg_acc) == 3
+
+
+def test_warmup_leaves_no_compile_on_the_round_clock(monkeypatch):
+    """wall_s is steady state: the warm-up compiles everything round 1
+    runs before its eval clock starts, the eval-round finite check and
+    the strategy's eval_params included (it used to compile inside the
+    first timed round)."""
+    import time
+    import types
+
+    compiles, marks = [], []
+
+    def listen(event, secs, **_):
+        if event.startswith("/jax/core/compile/"):
+            compiles.append(event)
+
+    def clock():  # 1st call: the round timer starts; 2nd: round 1's eval
+        marks.append(len(compiles))
+        return time.time()
+
+    monkeypatch.setattr(simulation, "time",
+                        types.SimpleNamespace(time=clock))
+    dkey, mkey = jax.random.split(jax.random.PRNGKey(0))
+    data = synthetic.label_shift(dkey, m=4, n=40, n_test=10, num_classes=4,
+                                 alpha=0.4, hw=(16, 16))
+    params0 = lenet.init(mkey, input_hw=(16, 16), channels=1, num_classes=4)
+    strat = ucfl.make_ucfl(lenet.apply, params0,
+                           FedConfig(epochs=1, batch_size=20),
+                           var_batch_size=20)
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        simulation.run(strat, lenet.apply, data, jax.random.PRNGKey(1),
+                       rounds=1, participation=ParticipationConfig(
+                           cohort_size=2))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert marks[0] > 0 and marks[1] == marks[0]
 
 
 def test_run_trials_reports_worst_std():

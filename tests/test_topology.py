@@ -47,7 +47,7 @@ from repro.federated.participation import (Cohort, ParticipationConfig,
                                            SelectionConfig)
 from repro.federated.topology import Topology
 from repro.models import lenet
-from tests.hypothesis_compat import HAVE_HYPOTHESIS, given, load_ci_profile, st
+from tests.hypothesis_compat import given, load_ci_profile, st
 
 load_ci_profile(max_examples=25)
 
@@ -270,28 +270,23 @@ def test_edge_partition_concrete():
                      np.full(4, 8, np.int32), np.zeros(4, bool))
 
 
-if HAVE_HYPOTHESIS:
-    @given(st.data())
-    def test_edge_partition_property(data_st):
-        m = data_st.draw(st.integers(2, 12), label="m")
-        num_edges = data_st.draw(st.integers(1, 5), label="E")
-        edge_of = data_st.draw(
-            st.lists(st.integers(0, num_edges - 1), min_size=m, max_size=m),
-            label="edge_of")
-        c = data_st.draw(st.integers(1, m), label="c")
-        take = data_st.draw(st.integers(0, c), label="take")
-        members = data_st.draw(
-            st.lists(st.integers(0, m - 1), min_size=take, max_size=take,
-                     unique=True), label="members")
-        idx = np.full(c, m, np.int32)
-        idx[:take] = np.sort(np.asarray(members, np.int32))
-        mask = np.zeros(c, bool)
-        mask[:take] = True
-        _check_partition(edge_of, num_edges, idx, mask)
-else:  # pragma: no cover - env-dependent
-    @given(st.none())
-    def test_edge_partition_property(_):
-        pass
+@given(st.data())
+def test_edge_partition_property(data_st):
+    m = data_st.draw(st.integers(2, 12), label="m")
+    num_edges = data_st.draw(st.integers(1, 5), label="E")
+    edge_of = data_st.draw(
+        st.lists(st.integers(0, num_edges - 1), min_size=m, max_size=m),
+        label="edge_of")
+    c = data_st.draw(st.integers(1, m), label="c")
+    take = data_st.draw(st.integers(0, c), label="take")
+    members = data_st.draw(
+        st.lists(st.integers(0, m - 1), min_size=take, max_size=take,
+                 unique=True), label="members")
+    idx = np.full(c, m, np.int32)
+    idx[:take] = np.sort(np.asarray(members, np.int32))
+    mask = np.zeros(c, bool)
+    mask[:take] = True
+    _check_partition(edge_of, num_edges, idx, mask)
 
 
 # ------------------------------------------------ (e) pareto selection
@@ -379,28 +374,23 @@ def test_with_selection_threads_policy():
     assert got.sampler == "pareto" and got.selection is sel
 
 
-if HAVE_HYPOTHESIS:
-    @given(st.data())
-    def test_pareto_cohort_contract_property(data_st):
-        """Any mass profile yields a valid padded cohort: prefix mask,
-        strictly increasing members, only positive-mass clients."""
-        m = data_st.draw(st.integers(2, 12), label="m")
-        c = data_st.draw(st.integers(1, m), label="c")
-        mass = np.asarray(data_st.draw(
-            st.lists(st.floats(0.0, 10.0), min_size=m, max_size=m),
-            label="mass"))
-        bias = data_st.draw(st.floats(0.25, 4.0), label="bias")
-        cfg = ParticipationConfig(
-            cohort_size=c, sampler="pareto", seed=3,
-            selection=SelectionConfig(compute=mass, bias=bias))
-        for rnd in (1, 2, 7):
-            co = pp.sample_cohort(cfg, rnd, m)  # Cohort.__post_init__
-            assert co.num_slots == c            # validates the contract
-            assert all(mass[i] > 0 for i in co.members)
-else:  # pragma: no cover - env-dependent
-    @given(st.none())
-    def test_pareto_cohort_contract_property(_):
-        pass
+@given(st.data())
+def test_pareto_cohort_contract_property(data_st):
+    """Any mass profile yields a valid padded cohort: prefix mask,
+    strictly increasing members, only positive-mass clients."""
+    m = data_st.draw(st.integers(2, 12), label="m")
+    c = data_st.draw(st.integers(1, m), label="c")
+    mass = np.asarray(data_st.draw(
+        st.lists(st.floats(0.0, 10.0), min_size=m, max_size=m),
+        label="mass"))
+    bias = data_st.draw(st.floats(0.25, 4.0), label="bias")
+    cfg = ParticipationConfig(
+        cohort_size=c, sampler="pareto", seed=3,
+        selection=SelectionConfig(compute=mass, bias=bias))
+    for rnd in (1, 2, 7):
+        co = pp.sample_cohort(cfg, rnd, m)  # Cohort.__post_init__
+        assert co.num_slots == c            # validates the contract
+        assert all(mass[i] > 0 for i in co.members)
 
 
 # --------------------------------------------- (f) capability boundaries
