@@ -1,0 +1,20 @@
+"""local_sgd_device_ms: device time of the ``fl.local_sgd`` stage (the
+cohort's local SGD, ``client.make_federated_local_sgd``) in one execution
+of the jitted cohort round: the union of the stage's op intervals in the
+traced trial over the round program's executions (the warm-up round and
+every timed round), as ``round_device_ms`` counts them. No such stage in
+the trace (a program without the ``fl.`` scopes): no reading."""
+
+from bench import stages
+from bench.trace import matching
+
+ROUND_MODULE = r"^jit__masked(\(|$|\.)"
+STAGE = "fl.local_sgd"
+
+
+def read(run):
+    red = stages.of(run)
+    if red is None or not red["stages"].get(STAGE):
+        return None
+    count, _ = matching(red["modules"], ROUND_MODULE)
+    return 1e3 * red["stages"][STAGE] / count if count else None

@@ -64,6 +64,7 @@ from repro.core.pytree import (  # noqa: F401  (re-export)
 from repro.federated import async_buffer
 from repro.federated import mesh as mesh_lib
 from repro.federated import participation
+from repro.federated import spans
 from repro.federated import topology as topology_lib
 from repro.federated import transport as transport_lib
 from repro.kernels import ops
@@ -107,9 +108,10 @@ def w_refresh_hook(refresh_cfg):
         return None
 
     def hook(pre_flat, post_flat, refresh, idx, mask, n):
-        obs = similarity.grad_proxy(pre_flat, post_flat)
-        return similarity.streaming_refresh(refresh, obs, idx, mask, n,
-                                            cfg=refresh_cfg)
+        with spans.scope(spans.W_REFRESH):
+            obs = similarity.grad_proxy(pre_flat, post_flat)
+            return similarity.streaming_refresh(refresh, obs, idx, mask, n,
+                                                cfg=refresh_cfg)
 
     return hook
 
@@ -527,12 +529,14 @@ def make_masked_round(train, mix, *, donate=True, sops=None,
         raise ValueError("transport requires the slab layout table")
 
     def core(params, ef, idx, mask, x, y, key, *args):
-        safe = aggregation.safe_gather_index(idx, x.shape[0])
-        keys = cohort_keys(key, x.shape[0], safe)
-        pc = gather(params, safe)
+        with spans.scope(spans.GATHER):
+            safe = aggregation.safe_gather_index(idx, x.shape[0])
+            keys = cohort_keys(key, x.shape[0], safe)
+            pc = gather(params, safe)
+            xc, yc = x[safe], y[safe]
+            tree = layout.unravel(pc) if layout is not None else pc
         if layout is not None:
-            updated = train(layout.unravel(pc), x[safe], y[safe], keys,
-                            *args)
+            updated = train(tree, xc, yc, keys, *args)
             post = layout.ravel(updated)
             if tstage is not None:
                 # the EF rows ride the cohort: gathered at the clamped
@@ -544,14 +548,16 @@ def make_masked_round(train, mix, *, donate=True, sops=None,
             if upload_stage is not None:
                 post, idx, mask = upload_stage(pc, post, idx, mask, key,
                                                x.shape[0])
-            return mix(params, post, idx, mask, *args), ef
-        updated = train(pc, x[safe], y[safe], keys, *args)
+            with spans.scope(spans.MIX):
+                return mix(params, post, idx, mask, *args), ef
+        updated = train(tree, xc, yc, keys, *args)
         if upload_stage is not None:
             flat, idx, mask = upload_stage(
                 stacked_ravel(pc), stacked_ravel(updated), idx, mask,
                 key, x.shape[0])
             updated = stacked_unravel(updated, flat)
-        return mix(params, updated, idx, mask, *args), ef
+        with spans.scope(spans.MIX):
+            return mix(params, updated, idx, mask, *args), ef
 
     if tstage is None:
         def body(params, idx, mask, x, y, key, *args):
@@ -844,14 +850,16 @@ def make_fedavg_async_round(train, acfg, *, impl=None, sops=None,
 
     def core(params, ef, abuf, idx, mask, x, y, key, n):
         m = x.shape[0]
-        safe = aggregation.safe_gather_index(idx, m)
-        keys = cohort_keys(key, m, safe)
-        pc = gather(params, safe)
+        with spans.scope(spans.GATHER):
+            safe = aggregation.safe_gather_index(idx, m)
+            keys = cohort_keys(key, m, safe)
+            pc = gather(params, safe)
+            xc, yc = x[safe], y[safe]
+            tree = layout.unravel(pc) if layout is not None else pc
+        updated = train(tree, xc, yc, keys, n)
         if layout is not None:
-            updated = train(layout.unravel(pc), x[safe], y[safe], keys, n)
             pre_flat, post_flat = pc, layout.ravel(updated)
         else:
-            updated = train(pc, x[safe], y[safe], keys, n)
             pre_flat = stacked_ravel(pc)
             post_flat = stacked_ravel(updated)
         if tstage is not None:
@@ -863,41 +871,42 @@ def make_fedavg_async_round(train, acfg, *, impl=None, sops=None,
             # delta rows never enter the pending buffer
             post_flat, idx, mask = upload_stage(pre_flat, post_flat, idx,
                                                 mask, key, m)
-        delta = post_flat - pre_flat
-        # FedAvg clients download the CURRENT global when sampled, so the
-        # upload's base version is the version at deposit time
-        base_ver = jnp.broadcast_to(abuf["version"], idx.shape)
-        abuf = async_buffer.deposit(abuf, delta, idx, mask, base_ver, m,
-                                    scatter=scatter)
-        flush = abuf["count"] >= flush_k
-        weights = async_buffer.staleness_weights(abuf, m, acfg.alpha)
-        tau = async_buffer.staleness(abuf)
-        applied = abuf["count"]
-        bvalid = async_buffer.valid_mask(abuf, m)
-        bsafe = aggregation.safe_gather_index(abuf["idx"], m)
+        with spans.scope(spans.MIX):
+            delta = post_flat - pre_flat
+            # FedAvg clients download the CURRENT global when sampled, so the
+            # upload's base version is the version at deposit time
+            base_ver = jnp.broadcast_to(abuf["version"], idx.shape)
+            abuf = async_buffer.deposit(abuf, delta, idx, mask, base_ver, m,
+                                        scatter=scatter)
+            flush = abuf["count"] >= flush_k
+            weights = async_buffer.staleness_weights(abuf, m, acfg.alpha)
+            tau = async_buffer.staleness(abuf)
+            applied = abuf["count"]
+            bvalid = async_buffer.valid_mask(abuf, m)
+            bsafe = aggregation.safe_gather_index(abuf["idx"], m)
 
-        def do_flush(params, abuf):
-            w = aggregation.masked_fedavg_weights(jnp.take(n, bsafe),
-                                                  bvalid, weights)
-            upd = (sops.buffer_gather(abuf["upd"]) if sops is not None
-                   else abuf["upd"])
-            # (1, d_al); stacked_unravel ignores the aligned-width tail
-            step = ops.mix_aggregate(w, upd, impl=impl)
-            new = jax.tree.map(jnp.add, params,
-                               stacked_unravel(params, step))
-            return new, async_buffer.flush_reset(abuf, m)
+            def do_flush(params, abuf):
+                w = aggregation.masked_fedavg_weights(jnp.take(n, bsafe),
+                                                      bvalid, weights)
+                upd = (sops.buffer_gather(abuf["upd"]) if sops is not None
+                       else abuf["upd"])
+                # (1, d_al); stacked_unravel ignores the aligned-width tail
+                step = ops.mix_aggregate(w, upd, impl=impl)
+                new = jax.tree.map(jnp.add, params,
+                                   stacked_unravel(params, step))
+                return new, async_buffer.flush_reset(abuf, m)
 
-        params, abuf = jax.lax.cond(flush, do_flush,
-                                    lambda p, b: (p, b), params, abuf)
-        if sops is not None:
-            # the flush's broadcast add is plain jnp — pin the output to
-            # the committed layout so round 2 doesn't recompile
-            params = sops.constrain(params)
-        metrics = async_buffer.flush_metrics(flush, applied, tau, weights,
-                                             abuf["count"])
-        # one broadcast stream hits the downlink only when a flush ships
-        # a new global
-        metrics["streams"] = flush.astype(jnp.int32)
+            params, abuf = jax.lax.cond(flush, do_flush,
+                                        lambda p, b: (p, b), params, abuf)
+            if sops is not None:
+                # the flush's broadcast add is plain jnp — pin the output to
+                # the committed layout so round 2 doesn't recompile
+                params = sops.constrain(params)
+            metrics = async_buffer.flush_metrics(flush, applied, tau, weights,
+                                                 abuf["count"])
+            # one broadcast stream hits the downlink only when a flush ships
+            # a new global
+            metrics["streams"] = flush.astype(jnp.int32)
         return params, ef, abuf, metrics
 
     if tstage is None:

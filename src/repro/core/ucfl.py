@@ -63,6 +63,7 @@ from repro.federated import async_buffer
 from repro.federated import client as fedclient
 from repro.federated import faults as faults_lib
 from repro.federated import mesh as mesh_lib
+from repro.federated import spans
 from repro.federated import topology as topology_lib
 from repro.federated import transport as transport_lib
 from repro.kernels import ops
@@ -91,13 +92,17 @@ def compute_collaboration(apply_fn, params0, data, *, var_batch_size=100,
         return full, similarity.sigma_sq(gmat, full)
 
     run = fedclient.client_vmap(one_client, chunk_size=chunk_size, mesh=mesh)
-    full, sig = run(data.x, data.y)
+    with spans.span(spans.INIT_GRADIENTS):
+        full, sig = run(data.x, data.y)
     delta_fn = functools.partial(similarity.pairwise_delta, impl=impl)
     mesh = mesh_lib.resolve(mesh)
     if mesh is not None:
         delta_fn = mesh_lib.replicated(delta_fn, mesh)
-    delta = delta_fn(full)
-    w = similarity.mixing_weights(delta, sig, data.n.astype(jnp.float32))
+    with spans.span(spans.INIT_GRAM):
+        delta = delta_fn(full)
+    with spans.span(spans.INIT_WEIGHTS):
+        w = similarity.mixing_weights(delta, sig,
+                                      data.n.astype(jnp.float32))
     return {"full_grads": full, "sigma_sq": sig, "delta": delta, "W": w}
 
 
@@ -193,29 +198,34 @@ def make_ucfl(apply_fn, params0, cfg: FedConfig = FedConfig(), *,
         labels = None
         onehot = None
         k = num_streams
-        if k == "auto":
-            kkey = silhouette_key if silhouette_key is not None else key
-            k, _ = clustering.choose_num_streams(kkey, w, impl=kernel_impl)
         if k is not None:
-            kmeans = functools.partial(clustering.kmeans, k=int(k),
-                                       impl=kernel_impl)
-            if sops.mesh is not None:
-                kmeans = mesh_lib.replicated(kmeans, sops.mesh)
-            res = kmeans(key, w)
-            labels = res.labels
-            # cluster-membership one-hots: lets the cohort round count the
-            # represented clusters (downlink streams) on device instead of
-            # a per-round np.unique host round-trip
-            onehot = jax.nn.one_hot(labels, int(k), dtype=jnp.float32)
-        stacked = layout.slab(params0, m)
-        state = {"params": stacked, "W": w, "labels": labels,
-                 "cluster_onehot": onehot, "streams": k, "collab": collab}
-        if refresh_hook is not None:
-            state["refresh"] = similarity.init_refresh_state(collab, m)
-        if tstage is not None:
-            state["ef"] = jnp.zeros_like(stacked)
-        if dstage is not None:
-            state["ef_dl"] = jnp.zeros_like(stacked)
+            with spans.span(spans.INIT_KMEANS):
+                if k == "auto":
+                    kkey = (silhouette_key if silhouette_key is not None
+                            else key)
+                    k, _ = clustering.choose_num_streams(kkey, w,
+                                                         impl=kernel_impl)
+                kmeans = functools.partial(clustering.kmeans, k=int(k),
+                                           impl=kernel_impl)
+                if sops.mesh is not None:
+                    kmeans = mesh_lib.replicated(kmeans, sops.mesh)
+                res = kmeans(key, w)
+                labels = res.labels
+                # cluster-membership one-hots: lets the cohort round count
+                # the represented clusters (downlink streams) on device
+                # instead of a per-round np.unique host round-trip
+                onehot = jax.nn.one_hot(labels, int(k), dtype=jnp.float32)
+        with spans.span(spans.INIT_SLAB):
+            stacked = layout.slab(params0, m)
+            state = {"params": stacked, "W": w, "labels": labels,
+                     "cluster_onehot": onehot, "streams": k,
+                     "collab": collab}
+            if refresh_hook is not None:
+                state["refresh"] = similarity.init_refresh_state(collab, m)
+            if tstage is not None:
+                state["ef"] = jnp.zeros_like(stacked)
+            if dstage is not None:
+                state["ef_dl"] = jnp.zeros_like(stacked)
         return state
 
     @functools.partial(jax.jit, static_argnames=("streams",))
@@ -295,11 +305,12 @@ def make_ucfl(apply_fn, params0, cfg: FedConfig = FedConfig(), *,
         # (fault/robust upload rewrite) -> masked mix + downlink serve.
         # ``ef``/``ef_dl`` are None when the owning stage is off (empty
         # pytrees — inert donation slots, exactly the stage-free trace).
-        safe = aggregation.safe_gather_index(idx, x.shape[0])
-        keys = common.cohort_keys(key, x.shape[0], safe)
-        pc = sops.gather(params, safe)
-        updated, _ = local(layout.unravel(pc), x[safe], y[safe], None,
-                           keys=keys)
+        with spans.scope(spans.GATHER):
+            safe = aggregation.safe_gather_index(idx, x.shape[0])
+            keys = common.cohort_keys(key, x.shape[0], safe)
+            pc = sops.gather(params, safe)
+            xc, yc, tree = x[safe], y[safe], layout.unravel(pc)
+        updated, _ = local(tree, xc, yc, None, keys=keys)
         post = layout.ravel(updated)
         if tstage is not None:
             # EF rows ride the cohort: gathered at the clamped indices,
@@ -310,13 +321,14 @@ def make_ucfl(apply_fn, params0, cfg: FedConfig = FedConfig(), *,
         if ustage is not None:
             post, idx, mask = ustage(pc, post, idx, mask, key, x.shape[0])
             safe = aggregation.safe_gather_index(idx, x.shape[0])
-        if topo is not None:
-            new, n_streams = _tiered_serve(params, w, labels, onehot,
-                                           post, idx, mask, safe)
-            return new, ef, ef_dl, n_streams
-        rows, n_streams = _mix_rows(w, labels, onehot, idx, mask, safe,
-                                    streams)
-        new, ef_dl = _serve(params, pc, post, rows, idx, mask, ef_dl)
+        with spans.scope(spans.MIX):
+            if topo is not None:
+                new, n_streams = _tiered_serve(params, w, labels, onehot,
+                                               post, idx, mask, safe)
+                return new, ef, ef_dl, n_streams
+            rows, n_streams = _mix_rows(w, labels, onehot, idx, mask, safe,
+                                        streams)
+            new, ef_dl = _serve(params, pc, post, rows, idx, mask, ef_dl)
         return new, ef, ef_dl, n_streams
 
     @functools.partial(jax.jit, static_argnames=("streams",),
@@ -332,11 +344,12 @@ def make_ucfl(apply_fn, params0, cfg: FedConfig = FedConfig(), *,
         # bounded, so quantization noise stays out of the Δ/σ²
         # statistics) and none of the demoted/Byzantine-trimmed rows (W
         # quarantines what the guard caught).
-        safe = aggregation.safe_gather_index(idx, x.shape[0])
-        keys = common.cohort_keys(key, x.shape[0], safe)
-        pc = sops.gather(params, safe)
-        updated, _ = local(layout.unravel(pc), x[safe], y[safe], None,
-                           keys=keys)
+        with spans.scope(spans.GATHER):
+            safe = aggregation.safe_gather_index(idx, x.shape[0])
+            keys = common.cohort_keys(key, x.shape[0], safe)
+            pc = sops.gather(params, safe)
+            xc, yc, tree = x[safe], y[safe], layout.unravel(pc)
+        updated, _ = local(tree, xc, yc, None, keys=keys)
         post = layout.ravel(updated)
         if tstage is not None:
             post, efc = tstage(pc, post, sops.gather(ef, safe))
@@ -350,15 +363,16 @@ def make_ucfl(apply_fn, params0, cfg: FedConfig = FedConfig(), *,
         refresh, w = refresh_hook(pc[..., :layout.dim],
                                   post[..., :layout.dim], refresh, idx,
                                   mask, n)
-        if topo is not None:
-            # the FRESH rules feed the same tiered serve — w_refresh and
-            # the two-tier engine compose without a second code path
-            new, n_streams = _tiered_serve(params, w, labels, onehot,
-                                           post, idx, mask, safe)
-            return new, ef, ef_dl, refresh, w, n_streams
-        rows, n_streams = _mix_rows(w, labels, onehot, idx, mask, safe,
-                                    streams)
-        new, ef_dl = _serve(params, pc, post, rows, idx, mask, ef_dl)
+        with spans.scope(spans.MIX):
+            if topo is not None:
+                # the FRESH rules feed the same tiered serve — w_refresh
+                # and the two-tier engine compose without a second path
+                new, n_streams = _tiered_serve(params, w, labels, onehot,
+                                               post, idx, mask, safe)
+                return new, ef, ef_dl, refresh, w, n_streams
+            rows, n_streams = _mix_rows(w, labels, onehot, idx, mask, safe,
+                                        streams)
+            new, ef_dl = _serve(params, pc, post, rows, idx, mask, ef_dl)
         return new, ef, ef_dl, refresh, w, n_streams
 
     amasked = _amasked_jit = None
@@ -377,11 +391,12 @@ def make_ucfl(apply_fn, params0, cfg: FedConfig = FedConfig(), *,
             # and flush rounds (lax.cond), so the one-compilation
             # guarantee of the barrier engine carries over.
             m = x.shape[0]
-            safe = aggregation.safe_gather_index(idx, m)
-            keys = common.cohort_keys(key, m, safe)
-            pc = sops.gather(params, safe)
-            updated, _ = local(layout.unravel(pc), x[safe], y[safe], None,
-                               keys=keys)
+            with spans.scope(spans.GATHER):
+                safe = aggregation.safe_gather_index(idx, m)
+                keys = common.cohort_keys(key, m, safe)
+                pc = sops.gather(params, safe)
+                xc, yc, tree = x[safe], y[safe], layout.unravel(pc)
+            updated, _ = local(tree, xc, yc, None, keys=keys)
             post_flat = layout.ravel(updated)
             if tstage is not None:
                 # the user-centric buffer holds MODELS, so the deposit is
@@ -397,37 +412,39 @@ def make_ucfl(apply_fn, params0, cfg: FedConfig = FedConfig(), *,
                 post_flat, idx, mask = ustage(pc, post_flat, idx, mask,
                                               key, m)
                 safe = aggregation.safe_gather_index(idx, m)
-            # a client trains from its OWN row, untouched since the flush
-            # that last wrote it — that version is the upload's base
-            base_ver = jnp.take(abuf["last_sync"], safe)
-            abuf = async_buffer.deposit(abuf, post_flat, idx,
-                                        mask, base_ver, m,
-                                        scatter=ascatter)
-            flush = abuf["count"] >= flush_k
-            weights = async_buffer.staleness_weights(abuf, m, acfg.alpha)
-            tau = async_buffer.staleness(abuf)
-            applied = abuf["count"]
-            bidx = abuf["idx"]
-            bvalid = async_buffer.valid_mask(abuf, m)
-            bsafe = aggregation.safe_gather_index(bidx, m)
+            with spans.scope(spans.MIX):
+                # a client trains from its OWN row, untouched since the flush
+                # that last wrote it — that version is the upload's base
+                base_ver = jnp.take(abuf["last_sync"], safe)
+                abuf = async_buffer.deposit(abuf, post_flat, idx,
+                                            mask, base_ver, m,
+                                            scatter=ascatter)
+                flush = abuf["count"] >= flush_k
+                weights = async_buffer.staleness_weights(abuf, m, acfg.alpha)
+                tau = async_buffer.staleness(abuf)
+                applied = abuf["count"]
+                bidx = abuf["idx"]
+                bvalid = async_buffer.valid_mask(abuf, m)
+                bsafe = aggregation.safe_gather_index(bidx, m)
 
-            def do_flush(params, abuf):
-                rows, n_streams = _mix_rows(w, labels, onehot, bidx, bvalid,
-                                            bsafe, streams, weights)
-                new = sops.mix_scatter_flat(params, abuf["upd"],
-                                            rows, bidx, bvalid,
-                                            impl=kernel_impl,
-                                            flat_sharded=sops.sharded)
-                return new, async_buffer.flush_reset(abuf, m), n_streams
+                def do_flush(params, abuf):
+                    rows, n_streams = _mix_rows(w, labels, onehot, bidx,
+                                                bvalid, bsafe, streams,
+                                                weights)
+                    new = sops.mix_scatter_flat(params, abuf["upd"],
+                                                rows, bidx, bvalid,
+                                                impl=kernel_impl,
+                                                flat_sharded=sops.sharded)
+                    return new, async_buffer.flush_reset(abuf, m), n_streams
 
-            def no_flush(params, abuf):
-                return params, abuf, jnp.zeros((), jnp.int32)
+                def no_flush(params, abuf):
+                    return params, abuf, jnp.zeros((), jnp.int32)
 
-            params, abuf, n_streams = jax.lax.cond(
-                flush, do_flush, no_flush, params, abuf)
-            metrics = {**async_buffer.flush_metrics(
-                flush, applied, tau, weights, abuf["count"]),
-                "streams": n_streams}
+                params, abuf, n_streams = jax.lax.cond(
+                    flush, do_flush, no_flush, params, abuf)
+                metrics = {**async_buffer.flush_metrics(
+                    flush, applied, tau, weights, abuf["count"]),
+                    "streams": n_streams}
             return params, ef, abuf, metrics
 
         _amasked_jit = _amasked

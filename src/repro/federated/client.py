@@ -30,6 +30,7 @@ import jax.numpy as jnp
 
 from repro.data.loader import epoch_batches
 from repro.federated import mesh as mesh_lib
+from repro.federated import spans
 from repro.optim import sgd_init, sgd_update
 
 
@@ -156,9 +157,10 @@ def make_federated_local_sgd(apply_fn, *, chunk_size=None, mesh=None, **kw):
         # with precomputed per-row keys — the masked cohort engine passes
         # client-indexed keys so a slot's randomness is independent of the
         # cohort's slot count (padding invariance).
-        if keys is None:
-            keys = jax.random.split(key, x.shape[0])
-        return run(stacked_params, x, y, keys, hook_state)
+        with spans.scope(spans.LOCAL_SGD):
+            if keys is None:
+                keys = jax.random.split(key, x.shape[0])
+            return run(stacked_params, x, y, keys, hook_state)
 
     return fed
 

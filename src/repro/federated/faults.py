@@ -64,6 +64,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import aggregation
+from repro.federated import spans
 
 _FOLD = 0xFA117  # fault key domain separator (never collides with training)
 
@@ -218,11 +219,12 @@ def upload_stage(faults_cfg: FaultConfig | None, robust_cfg=None, schema=None):
         return None
 
     def stage(pre_flat, post_flat, idx, mask, key, m):
-        if faults_cfg is not None:
-            post_flat, idx, mask = inject(faults_cfg, pre_flat, post_flat, idx, mask, key, m)
-        post_flat, idx, mask = finite_guard(post_flat, idx, mask, m, schema)
-        if rstage is not None:
-            post_flat, idx, mask = rstage(post_flat, idx, mask, m)
-        return post_flat, idx, mask
+        with spans.scope(spans.UPLOAD):
+            if faults_cfg is not None:
+                post_flat, idx, mask = inject(faults_cfg, pre_flat, post_flat, idx, mask, key, m)
+            post_flat, idx, mask = finite_guard(post_flat, idx, mask, m, schema)
+            if rstage is not None:
+                post_flat, idx, mask = rstage(post_flat, idx, mask, m)
+            return post_flat, idx, mask
 
     return stage

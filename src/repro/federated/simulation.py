@@ -25,6 +25,11 @@ and EXCLUDED from ``wall_s`` — eval frequency is a measurement choice,
 not a property of the round engine, and benchmark consumers comparing
 engines by ``wall_s`` must not see it.
 
+Tracing: under ``jax.profiler.trace`` the run marks its host work with
+the ``fl.`` spans of :mod:`repro.federated.spans` — ``fl.init``,
+``fl.warmup``, and per round a profiler step ``fl.round`` holding
+``fl.cohort``, ``fl.dispatch``, ``fl.check_finite`` and ``fl.evaluate``.
+
 Evaluation: ``eval_chunk`` bounds the client axis of the per-round
 accuracy pass with the same ``lax.map`` machinery as training, so eval
 no longer materializes O(m · test_set) activations at once; pass
@@ -50,6 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.federated import participation as part
+from repro.federated import spans
 from repro.federated.client import evaluate
 
 
@@ -142,6 +148,30 @@ def _check_finite_state(strategy, state, rnd):
             "check_finite=False to simulation.run to opt out")
 
 
+def _warm_up(strategy, state, data, key, participation, check_finite):
+    """One discarded round on a copy of ``state``, synced, so that
+    ``strategy.round`` (and the finite check) compile before the timer."""
+    m = data.num_clients
+    wcohort = part.sample_cohort(participation, 1, m, data.n)
+    if wcohort is not None and len(wcohort) == 0:
+        # round 1 is all-offline; every cohort of a policy shares one
+        # compiled shape, so warm up with a synthetic one-member
+        # cohort of the same slot count instead of skipping (which
+        # would push the compile into the timed region)
+        idx = np.full(wcohort.num_slots, m, np.int32)
+        idx[0] = 0
+        mask = np.zeros(wcohort.num_slots, bool)
+        mask[0] = True
+        wcohort = part.Cohort(indices=idx, mask=mask)
+    wstate, _ = strategy.round(
+        donation_safe_copy(state), data,
+        jax.random.fold_in(key, 0x5EED), wcohort)
+    jax.block_until_ready(wstate)
+    if check_finite:  # the eval-round finite check runs on the clock
+        jax.block_until_ready(
+            _client_rows_finite(strategy.eval_params(wstate)))
+
+
 def run(strategy, apply_fn, data, key, *, rounds: int, eval_every: int = 1,
         verbose: bool = False, participation: part.ParticipationConfig | None
         = None, warmup: bool = True, eval_chunk: int | None = None,
@@ -154,7 +184,8 @@ def run(strategy, apply_fn, data, key, *, rounds: int, eval_every: int = 1,
         # client bias factors; the strategy never draws cohorts itself
         participation = part.with_selection(participation, selection)
     key, ikey = jax.random.split(key)
-    state = strategy.init(ikey, data)
+    with spans.span(spans.INIT):
+        state = strategy.init(ikey, data)
     hist = History(strategy.name, [], [], [], [])
     # None = on unless the strategy deliberately injects faults (its
     # finite guard owns degradation there; raising would defeat it)
@@ -162,37 +193,22 @@ def run(strategy, apply_fn, data, key, *, rounds: int, eval_every: int = 1,
         check_finite = not strategy.injects_faults
 
     if warmup:  # compile strategy.round outside the timed region
-        wcohort = part.sample_cohort(participation, 1, m, data.n)
-        if wcohort is not None and len(wcohort) == 0:
-            # round 1 is all-offline; every cohort of a policy shares one
-            # compiled shape, so warm up with a synthetic one-member
-            # cohort of the same slot count instead of skipping (which
-            # would push the compile into the timed region)
-            idx = np.full(wcohort.num_slots, m, np.int32)
-            idx[0] = 0
-            mask = np.zeros(wcohort.num_slots, bool)
-            mask[0] = True
-            wcohort = part.Cohort(indices=idx, mask=mask)
-        wstate, _ = strategy.round(
-            donation_safe_copy(state), data,
-            jax.random.fold_in(key, 0x5EED), wcohort)
-        jax.block_until_ready(wstate)
-        if check_finite:  # the eval-round finite check runs on the clock
-            jax.block_until_ready(
-                _client_rows_finite(strategy.eval_params(wstate)))
-        del wstate
+        with spans.span(spans.WARMUP):
+            _warm_up(strategy, state, data, key, participation, check_finite)
 
-    t0 = time.time()
+    t0 = time.perf_counter()
 
     def do_eval(rnd, metrics):
         if check_finite:
-            _check_finite_state(strategy, state, rnd)
-        te = time.time()
-        accs = np.asarray(
-            evaluate(apply_fn, strategy.eval_params(state), data.x_test,
-                     data.y_test, batch=eval_chunk, mesh=eval_mesh)
-        )
-        hist.eval_s += time.time() - te
+            with spans.span(spans.CHECK_FINITE):
+                _check_finite_state(strategy, state, rnd)
+        with spans.span(spans.EVALUATE):
+            te = time.perf_counter()
+            accs = np.asarray(
+                evaluate(apply_fn, strategy.eval_params(state), data.x_test,
+                         data.y_test, batch=eval_chunk, mesh=eval_mesh)
+            )
+            hist.eval_s += time.perf_counter() - te
         hist.rounds.append(rnd)
         hist.avg_acc.append(float(accs.mean()))
         hist.worst_acc.append(float(accs.min()))
@@ -207,23 +223,30 @@ def run(strategy, apply_fn, data, key, *, rounds: int, eval_every: int = 1,
 
     metrics: Dict[str, Any] = {}
     for rnd in range(1, rounds + 1):
-        key, rkey = jax.random.split(key)
-        cohort = part.sample_cohort(participation, rnd, m, data.n)
-        if cohort is not None and len(cohort) == 0:
-            # nobody available this round: the server idles and no
-            # training/aggregation runs — but time still passes for
-            # per-client bookkeeping (e.g. the streaming W refresh's
-            # staleness counters), which the strategy's skip hook owns.
-            # Skipping state entirely here used to freeze the counters
-            # for rounds nobody attends.
-            if strategy.skip_round is not None:
-                state = strategy.skip_round(state)
-            metrics = {"streams": 0, "cohort_size": 0, "skipped": True}
-        else:
-            state, metrics = strategy.round(state, data, rkey, cohort)
-        if rnd % eval_every == 0 or rnd == rounds:
-            do_eval(rnd, metrics)
-    hist.wall_s = time.time() - t0 - hist.eval_s
+        # each iteration is one profiler step, so XProf shows rounds
+        with jax.profiler.StepTraceAnnotation(spans.ROUND, step_num=rnd):
+            with spans.span(spans.COHORT):
+                key, rkey = jax.random.split(key)
+                cohort = part.sample_cohort(participation, rnd, m, data.n)
+            with spans.span(spans.DISPATCH):
+                if cohort is not None and len(cohort) == 0:
+                    # nobody available this round: the server idles and
+                    # no training/aggregation runs — but time still
+                    # passes for per-client bookkeeping (e.g. the
+                    # streaming W refresh's staleness counters), which
+                    # the strategy's skip hook owns. Skipping state
+                    # entirely here used to freeze the counters for
+                    # rounds nobody attends.
+                    if strategy.skip_round is not None:
+                        state = strategy.skip_round(state)
+                    metrics = {"streams": 0, "cohort_size": 0,
+                               "skipped": True}
+                else:
+                    state, metrics = strategy.round(state, data, rkey,
+                                                    cohort)
+            if rnd % eval_every == 0 or rnd == rounds:
+                do_eval(rnd, metrics)
+    hist.wall_s = time.perf_counter() - t0 - hist.eval_s
     hist.state = state
     return hist
 

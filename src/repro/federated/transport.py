@@ -100,6 +100,7 @@ import dataclasses
 
 import jax.numpy as jnp
 
+from repro.federated import spans
 from repro.kernels import ops
 
 _QMAX = {"int8": 127.0, "fp8": 448.0}  # fp8 = e4m3 finite max
@@ -309,12 +310,8 @@ def make_wire_stage(schema: WireSchema, transport, direction: str = "uplink"):
     if not any(s.coding == "delta" for s in streams):
         return None
     slices = schema.slices(direction)
-    if len(streams) == 1:
-        # the single-stream stage IS make_stage (no concat in the trace):
-        # every pre-schema single-delta trajectory stays bit-identical
-        return make_stage(transport)
 
-    def stage(pre, post, ef):
+    def per_stream(pre, post, ef):
         outs, efs = [], []
         for s, (lo, hi) in zip(streams, slices):
             p, q, e = pre[..., lo:hi], post[..., lo:hi], ef[..., lo:hi]
@@ -327,5 +324,13 @@ def make_wire_stage(schema: WireSchema, transport, direction: str = "uplink"):
                 outs.append(q)
                 efs.append(jnp.zeros_like(e))
         return jnp.concatenate(outs, axis=-1), jnp.concatenate(efs, axis=-1)
+
+    # the single-stream stage IS make_stage (no concat in the trace):
+    # every pre-schema single-delta trajectory stays bit-identical
+    inner = make_stage(transport) if len(streams) == 1 else per_stream
+
+    def stage(pre, post, ef):
+        with spans.scope(spans.WIRE):
+            return inner(pre, post, ef)
 
     return stage

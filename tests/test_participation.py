@@ -404,10 +404,10 @@ def test_warmup_leaves_no_compile_on_the_round_clock(monkeypatch):
 
     def clock():  # 1st call: the round timer starts; 2nd: round 1's eval
         marks.append(len(compiles))
-        return time.time()
+        return time.perf_counter()
 
     monkeypatch.setattr(simulation, "time",
-                        types.SimpleNamespace(time=clock))
+                        types.SimpleNamespace(perf_counter=clock))
     dkey, mkey = jax.random.split(jax.random.PRNGKey(0))
     data = synthetic.label_shift(dkey, m=4, n=40, n_test=10, num_classes=4,
                                  alpha=0.4, hw=(16, 16))
