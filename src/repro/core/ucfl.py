@@ -69,9 +69,41 @@ from repro.federated import transport as transport_lib
 from repro.kernels import ops
 
 
+@functools.partial(jax.jit, static_argnames=("apply_fn", "var_batch_size",
+                                             "chunk_size", "mesh"))
+def _special_round_uploads(params0, x, y, *, apply_fn, var_batch_size,
+                           chunk_size, mesh):
+    # every client's Eq. 10 upload, (m, d) full gradients and (m,) σ²
+    grad_fn = jax.grad(fedclient.make_loss(apply_fn))
+
+    def one_client(x, y):
+        xb, yb = fixed_partition(x, y, var_batch_size)
+        g = jax.vmap(grad_fn, in_axes=(None, 0, 0))(params0, xb, yb)
+        gmat = stacked_ravel(g, lead=1)  # (K, d)
+        full = jnp.mean(gmat, axis=0)
+        return full, similarity.sigma_sq(gmat, full)
+
+    return fedclient.client_vmap(one_client, chunk_size=chunk_size,
+                                 mesh=mesh)(x, y)
+
+
+@jax.jit
+def _delta_and_weights(gram, sig, n):
+    # Δ from the Gram matrix, then Eq. 9's W
+    delta = ops.delta_from_gram(gram)
+    return delta, similarity.mixing_weights(delta, sig, n.astype(jnp.float32))
+
+
 def compute_collaboration(apply_fn, params0, data, *, var_batch_size=100,
                           impl=None, chunk_size=None, mesh=None):
     """Run the special pre-training round; returns the dict of §IV-A.
+
+    Three compiled launches, each under its own host span:
+    ``fl.init.gradients`` the per-client gradient pass with σ² (one
+    program, compiled once per ``apply_fn``, sizes and settings, so a
+    later init on the same shapes compiles nothing), ``fl.init.gram`` the
+    Gram program (``gram_pallas`` on the chip, a program of its own) and
+    ``fl.init.weights`` Δ and W from the Gram.
 
     ``chunk_size`` bounds the client axis with the same ``lax.map``
     machinery as local training: each chunk materializes only its own
@@ -81,28 +113,18 @@ def compute_collaboration(apply_fn, params0, data, *, var_batch_size=100,
     client axis across devices (chunking within each shard) when the
     shard count divides m.
     """
-    loss = fedclient.make_loss(apply_fn)
-    grad_fn = jax.grad(loss)
-
-    def one_client(x, y):
-        xb, yb = fixed_partition(x, y, var_batch_size)
-        g = jax.vmap(grad_fn, in_axes=(None, 0, 0))(params0, xb, yb)
-        gmat = stacked_ravel(g, lead=1)  # (K, d)
-        full = jnp.mean(gmat, axis=0)
-        return full, similarity.sigma_sq(gmat, full)
-
-    run = fedclient.client_vmap(one_client, chunk_size=chunk_size, mesh=mesh)
-    with spans.span(spans.INIT_GRADIENTS):
-        full, sig = run(data.x, data.y)
-    delta_fn = functools.partial(similarity.pairwise_delta, impl=impl)
     mesh = mesh_lib.resolve(mesh)
+    with spans.span(spans.INIT_GRADIENTS):
+        full, sig = _special_round_uploads(
+            params0, data.x, data.y, apply_fn=apply_fn,
+            var_batch_size=var_batch_size, chunk_size=chunk_size, mesh=mesh)
+    gram_fn = functools.partial(ops.gram, impl=impl)
     if mesh is not None:
-        delta_fn = mesh_lib.replicated(delta_fn, mesh)
+        gram_fn = mesh_lib.replicated(gram_fn, mesh)
     with spans.span(spans.INIT_GRAM):
-        delta = delta_fn(full)
+        gram = gram_fn(full)
     with spans.span(spans.INIT_WEIGHTS):
-        w = similarity.mixing_weights(delta, sig,
-                                      data.n.astype(jnp.float32))
+        delta, w = _delta_and_weights(gram, sig, data.n)
     return {"full_grads": full, "sigma_sq": sig, "delta": delta, "W": w}
 
 
@@ -161,6 +183,7 @@ def make_ucfl(apply_fn, params0, cfg: FedConfig = FedConfig(), *,
     acfg = cfg.async_buffer
     sops = common.StateOps(cfg.mesh, cfg.shard_state)
     layout = flat.LayoutTable.build(params0)
+    slab = jax.jit(layout.slab, static_argnums=1)
     # wire schema: one delta upload either way; full personalization also
     # delta-codes its per-client downlink (each receiver's reference is
     # its own round-start row), while the clustered variant's centroid
@@ -216,7 +239,7 @@ def make_ucfl(apply_fn, params0, cfg: FedConfig = FedConfig(), *,
                 # instead of a per-round np.unique host round-trip
                 onehot = jax.nn.one_hot(labels, int(k), dtype=jnp.float32)
         with spans.span(spans.INIT_SLAB):
-            stacked = layout.slab(params0, m)
+            stacked = slab(params0, m)
             state = {"params": stacked, "W": w, "labels": labels,
                      "cluster_onehot": onehot, "streams": k,
                      "collab": collab}

@@ -10,9 +10,11 @@ change no number and no compiled schedule. Every name starts with
 Host spans (``simulation.run``, ``ucfl``):
 
 * ``fl.init`` — ``strategy.init``; inside it ``fl.init.gradients`` (the
-  special round's per-client gradient pass), ``fl.init.gram`` (the Δ Gram
-  program), ``fl.init.weights`` (Eq. 9 W), ``fl.init.kmeans`` (§IV-B
-  clustering, when set) and ``fl.init.slab`` (the stacked state buffers);
+  special round's per-client gradient pass with σ²), ``fl.init.gram``
+  (the Δ Gram program), ``fl.init.weights`` (Δ and Eq. 9 W),
+  ``fl.init.kmeans`` (§IV-B clustering, when set) and ``fl.init.slab``
+  (the stacked state buffers). For ucfl the gradient, Gram, weights
+  and slab spans each wrap one compiled launch;
 * ``fl.warmup`` — the warm-up round on a copy, its sync and finite check;
 * ``fl.round`` — one rounds-loop iteration, a profiler step; inside it
   ``fl.cohort`` (key split and cohort draw), ``fl.dispatch``

@@ -119,15 +119,25 @@ def cohort_gather(full, idx, *, impl=None):
     return cohort_rows.gather_rows(full, idx)
 
 
-def pairwise_delta(g, *, impl=None, block_d=None):
-    """Pairwise squared distances between rows of g (m, d) -> (m, m)."""
+def gram(g, *, impl=None, block_d=None):
+    """Gram matrix ``g g^T`` of (m, d) rows -> (m, m) f32."""
     impl, _ = _split_variant(_auto_impl(impl))
     if impl == "ref":
-        return ref.pairwise_delta(g)
+        return ref.gram(g)
     kwargs = {} if block_d is None else {"block_d": block_d}
-    gr = gram_pallas(g, interpret=(impl == "interpret"), **kwargs)
+    return gram_pallas(g, interpret=(impl == "interpret"), **kwargs)
+
+
+def delta_from_gram(gr):
+    """Pairwise squared distances ``G_ii + G_jj - 2 G_ij`` from a Gram
+    matrix, clamped at 0 (round-off can push a distance below it)."""
     sq = jnp.diag(gr)
     return jnp.maximum(sq[:, None] + sq[None, :] - 2.0 * gr, 0.0)
+
+
+def pairwise_delta(g, *, impl=None, block_d=None):
+    """Pairwise squared distances between rows of g (m, d) -> (m, m)."""
+    return delta_from_gram(gram(g, impl=impl, block_d=block_d))
 
 
 def kmeans_assign(points, centroids, *, impl=None):

@@ -1,0 +1,74 @@
+"""The compiled Eq. 9 special round (``ucfl.compute_collaboration``).
+
+The per-client gradient pass with σ², the Gram and Δ with W run as a few
+compiled launches: they give what the same maths gives op by op, and a
+strategy's second init on the same data compiles nothing."""
+import functools
+
+import jax
+import numpy as np
+import pytest
+
+from repro.core import FedConfig, ucfl
+from repro.data import synthetic
+from repro.kernels import ops
+from repro.models import lenet
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+@functools.lru_cache(maxsize=1)
+def _setup():
+    key = jax.random.PRNGKey(23)
+    dkey, mkey = jax.random.split(key)
+    # m = 7 leaves the last chunk of 3 padded
+    data = synthetic.label_shift(dkey, m=7, n=60, n_test=10, num_classes=5,
+                                 hw=(16, 16), channels=1)
+    params0 = lenet.init(mkey, input_hw=(16, 16), channels=1, num_classes=5)
+    return data, params0
+
+
+@pytest.mark.parametrize("chunk_size", [None, 3])
+def test_compiled_collaboration_matches_eager(chunk_size):
+    data, params0 = _setup()
+    run = functools.partial(ucfl.compute_collaboration, lenet.apply, params0,
+                            data, var_batch_size=20, chunk_size=chunk_size)
+    compiled = run()
+    with jax.disable_jit():
+        eager = run()
+        # Δ and W of the same gradients, op by op
+        gram = ops.gram(compiled["full_grads"])
+        delta, w = ucfl._delta_and_weights(gram, compiled["sigma_sq"], data.n)
+    np.testing.assert_array_equal(np.asarray(compiled["delta"]),
+                                  np.asarray(delta))
+    np.testing.assert_array_equal(np.asarray(compiled["W"]), np.asarray(w))
+    for key in ("full_grads", "sigma_sq", "W"):
+        np.testing.assert_allclose(np.asarray(compiled[key]),
+                                   np.asarray(eager[key]),
+                                   rtol=1e-5, atol=1e-6, err_msg=key)
+    # G_ii + G_jj - 2 G_ij cancels: gradients a few ulps apart (another
+    # reduction order) give Δs a few ulps of the largest Gram entry apart
+    ulp = np.finfo(np.float32).eps * float(np.max(np.asarray(gram)))
+    np.testing.assert_allclose(np.asarray(compiled["delta"]),
+                               np.asarray(eager["delta"]),
+                               rtol=1e-5, atol=8 * ulp)
+
+
+def test_second_init_compiles_nothing():
+    data, params0 = _setup()
+    cfg = FedConfig(lr=0.1, momentum=0.9, epochs=1, batch_size=20)
+    strategy = ucfl.make_ucfl(lenet.apply, params0, cfg, var_batch_size=20)
+    key = jax.random.PRNGKey(0)
+    jax.block_until_ready(strategy.init(key, data))
+    compiled = []
+
+    def on_duration(event, secs, **_):
+        if event == COMPILE_EVENT:
+            compiled.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        jax.block_until_ready(strategy.init(key, data))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert compiled == []
