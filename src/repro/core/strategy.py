@@ -83,7 +83,7 @@ class FedConfig:
     """Paper §V-A hyperparameters.
 
     ``chunk_size`` bounds peak client-axis memory: local SGD runs as a
-    sequential ``lax.map`` over chunks of that many vmapped clients (see
+    sequential loop over chunks of that many vmapped clients (see
     :func:`repro.federated.client.make_federated_local_sgd`); ``None``
     keeps the single monolithic vmap.
 

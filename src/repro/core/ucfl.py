@@ -69,6 +69,30 @@ from repro.federated import transport as transport_lib
 from repro.kernels import ops
 
 
+# The most Eq. 10 minibatch samples the special round's gradient program
+# takes at once when no chunk is set: the paper cell's whole batch, 20
+# clients of 500 samples, which it runs unchunked.
+INIT_CHUNK_SAMPLES = 20 * 500
+
+
+def init_chunk(m, n, var_batch_size, chunk_size=None):
+    """Clients per chunk of the special round's gradient program; m (or
+    more) runs the whole client axis at once.
+
+    An explicit ``chunk_size`` wins. Otherwise the chunk is the largest
+    divisor of m whose clients hold at most ``INIT_CHUNK_SAMPLES`` samples
+    of Eq. 10 minibatches (a client of n samples holds
+    ``n // var_batch_size`` of them), so no chunk is padded; where that
+    divisor holds under half of it (m prime, say), the most clients that
+    fit, with a padded last chunk."""
+    if chunk_size is not None:
+        return chunk_size
+    per_client = max(n // var_batch_size * var_batch_size, 1)
+    most = min(max(INIT_CHUNK_SAMPLES // per_client, 1), m)
+    best = max(c for c in range(1, most + 1) if m % c == 0)
+    return best if 2 * best * per_client >= INIT_CHUNK_SAMPLES else most
+
+
 @functools.partial(jax.jit, static_argnames=("apply_fn", "var_batch_size",
                                              "chunk_size", "mesh"))
 def _special_round_uploads(params0, x, y, *, apply_fn, var_batch_size,
@@ -83,42 +107,49 @@ def _special_round_uploads(params0, x, y, *, apply_fn, var_batch_size,
         full = jnp.mean(gmat, axis=0)
         return full, similarity.sigma_sq(gmat, full)
 
-    return fedclient.client_vmap(one_client, chunk_size=chunk_size,
-                                 mesh=mesh)(x, y)
+    with spans.scope(spans.INIT_GRADIENTS):
+        return fedclient.client_vmap(one_client, chunk_size=chunk_size,
+                                     mesh=mesh)(x, y)
 
 
 @jax.jit
 def _delta_and_weights(gram, sig, n):
     # Δ from the Gram matrix, then Eq. 9's W
-    delta = ops.delta_from_gram(gram)
-    return delta, similarity.mixing_weights(delta, sig, n.astype(jnp.float32))
+    with spans.scope(spans.INIT_WEIGHTS):
+        delta = ops.delta_from_gram(gram)
+        return delta, similarity.mixing_weights(delta, sig,
+                                                n.astype(jnp.float32))
 
 
 def compute_collaboration(apply_fn, params0, data, *, var_batch_size=100,
                           impl=None, chunk_size=None, mesh=None):
     """Run the special pre-training round; returns the dict of §IV-A.
 
-    Three compiled launches, each under its own host span:
-    ``fl.init.gradients`` the per-client gradient pass with σ² (one
-    program, compiled once per ``apply_fn``, sizes and settings, so a
-    later init on the same shapes compiles nothing), ``fl.init.gram`` the
-    Gram program (``gram_pallas`` on the chip, a program of its own) and
-    ``fl.init.weights`` Δ and W from the Gram.
+    Three compiled launches, each under its own host span and, on the
+    device, a stage scope of the same name: ``fl.init.gradients`` the
+    per-client gradient pass with σ² (one program, compiled once per
+    ``apply_fn``, sizes and settings, so a later init on the same shapes
+    compiles nothing), ``fl.init.gram`` the Gram program (``gram_pallas``
+    on the chip, a program of its own) and ``fl.init.weights`` Δ and W
+    from the Gram.
 
-    ``chunk_size`` bounds the client axis with the same ``lax.map``
-    machinery as local training: each chunk materializes only its own
-    (chunk, K, d) minibatch-gradient stack and immediately reduces it to
-    the (chunk, d) full gradients + (chunk,) variance estimates, so init
-    memory is O(chunk·K·d) instead of O(m·K·d). ``mesh`` shards the
-    client axis across devices (chunking within each shard) when the
-    shard count divides m.
+    The gradient pass goes over the client axis in chunks
+    (:func:`init_chunk`: ``chunk_size`` when given, else derived from the
+    client count and data size) with the same loop as local training:
+    each chunk materializes only its own (chunk, K, d) minibatch-gradient
+    stack and immediately reduces it to the (chunk, d) full gradients +
+    (chunk,) variance estimates, so init memory is O(chunk·K·d) instead
+    of O(m·K·d). ``mesh`` shards the client axis across devices
+    (chunking within each shard) when the shard count divides m.
     """
     mesh = mesh_lib.resolve(mesh)
+    chunk = init_chunk(data.x.shape[0], data.x.shape[1], var_batch_size,
+                       chunk_size)
     with spans.span(spans.INIT_GRADIENTS):
         full, sig = _special_round_uploads(
             params0, data.x, data.y, apply_fn=apply_fn,
-            var_batch_size=var_batch_size, chunk_size=chunk_size, mesh=mesh)
-    gram_fn = functools.partial(ops.gram, impl=impl)
+            var_batch_size=var_batch_size, chunk_size=chunk, mesh=mesh)
+    gram_fn = functools.partial(ops.gram, impl=impl, stage=spans.INIT_GRAM)
     if mesh is not None:
         gram_fn = mesh_lib.replicated(gram_fn, mesh)
     with spans.span(spans.INIT_GRAM):

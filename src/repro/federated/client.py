@@ -7,7 +7,7 @@ per-step gradient corrections (FedProx proximal term, SCAFFOLD control
 variates, Ditto/pFedMe regularizers) without duplicating the loop.
 
 Memory knob: ``make_federated_local_sgd(..., chunk_size=C)`` replaces the
-monolithic client vmap with a sequential ``lax.map`` over ⌈m/C⌉ chunks of
+monolithic client vmap with a sequential loop over ⌈m/C⌉ chunks of
 C clients each, so peak activation memory is O(C) instead of O(m) while
 per-client results stay identical (same per-client PRNG keys). Use it to
 scale the client axis (or a sampled cohort) to thousands of clients on a
@@ -88,11 +88,12 @@ def client_vmap(fn, *, chunk_size=None, mesh=None):
     """vmap ``fn`` over a shared leading client axis of every argument.
 
     With ``chunk_size=C`` the client axis is instead processed as a
-    sequential ``lax.map`` over chunks of C vmapped clients (last chunk
-    padded by repeating index 0; padding results are discarded), bounding
-    peak memory by the chunk instead of the full axis while keeping
-    per-client results identical to the monolithic vmap. Arguments that
-    are ``None`` (empty pytrees) pass through unmapped.
+    sequential loop over chunks of C vmapped clients (last chunk padded
+    by repeating index 0; padding results are discarded), each chunk's
+    rows taken inside the loop, bounding peak memory by the chunk instead
+    of the full axis while keeping per-client results identical to the
+    monolithic vmap. Arguments that are ``None`` (empty pytrees) pass
+    through unmapped.
 
     With ``mesh`` (a :mod:`repro.federated.mesh` knob: Mesh | int |
     ``"auto"``) the client axis is partitioned across the mesh's devices
@@ -117,19 +118,34 @@ def client_vmap(fn, *, chunk_size=None, mesh=None):
         nc = -(-m // chunk_size)
         pad = nc * chunk_size - m
 
-        def prep(t):
-            def leaf(a):
-                if pad:
-                    a = jnp.concatenate(
-                        [a, jnp.repeat(a[:1], pad, axis=0)], axis=0)
-                return a.reshape((nc, chunk_size) + a.shape[1:])
-            return jax.tree.map(leaf, t)
+        # each chunk's rows are taken inside the loop and its results
+        # written in place into the (m, ...) output: padding the whole
+        # axis would copy every input, and reshaping stacked (nc, C, ...)
+        # results copies all of them again where C is not a multiple of
+        # the device layout's 8-row tile
+        def rows(i):
+            start = i * chunk_size
+            if not pad:
+                return lambda a: jax.lax.dynamic_slice_in_dim(
+                    a, start, chunk_size)
+            # the padded last chunk repeats row 0; its results are dropped
+            idx = start + jnp.arange(chunk_size)
+            idx = jnp.where(idx < m, idx, 0)
+            return lambda a: jnp.take(a, idx, axis=0)
 
-        def unprep(t):
+        def body(i, out):
+            res = vfn(*jax.tree.map(rows(i), args))
             return jax.tree.map(
-                lambda a: a.reshape((nc * chunk_size,) + a.shape[2:])[:m], t)
+                lambda o, r: jax.lax.dynamic_update_slice_in_dim(
+                    o, r, i * chunk_size, 0), out, res)
 
-        return unprep(jax.lax.map(lambda chunk: vfn(*chunk), prep(args)))
+        shapes = jax.eval_shape(
+            lambda *a: vfn(*a), *jax.tree.map(lambda a: a[:chunk_size], args))
+        out = jax.tree.map(lambda s: jnp.zeros((nc * chunk_size,)
+                                               + s.shape[1:], s.dtype),
+                           shapes)
+        out = jax.lax.fori_loop(0, nc, body, out)
+        return jax.tree.map(lambda a: a[:m], out) if pad else out
 
     def mapped(*args):
         m = jax.tree.leaves(args)[0].shape[0]
@@ -186,7 +202,7 @@ def evaluate(apply_fn, stacked_params, x_test, y_test, *, batch=None,
 
     ``batch`` bounds the client axis via :func:`client_vmap`'s
     ``chunk_size`` path: accuracies are computed as a sequential
-    ``lax.map`` over chunks of that many clients, so peak activation
+    loop over chunks of that many clients, so peak activation
     memory is O(batch · test_set) instead of O(m · test_set). ``None``
     keeps the fully-parallel vmap (identical results). ``mesh`` shards
     the client axis across devices; logits then match the unsharded

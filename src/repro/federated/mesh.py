@@ -1,7 +1,7 @@
 """Device mesh for sharding the cohort/client axis across devices.
 
 ``chunk_size`` bounds single-host memory by running local SGD as a
-*sequential* ``lax.map`` over chunks — cohort wall-time grows linearly
+*sequential* loop over chunks — cohort wall-time grows linearly
 with cohort size even when devices sit idle. This module adds the
 *parallel* scale axis: a 1-D :class:`jax.sharding.Mesh` over a
 ``clients`` axis partitions the padded cohort slots across devices, so

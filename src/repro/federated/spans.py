@@ -21,9 +21,11 @@ Host spans (``simulation.run``, ``ucfl``):
   (``strategy.round``), ``fl.check_finite`` and ``fl.evaluate`` (the
   pass ``History.eval_s`` times).
 
-Device stages (the cohort round programs): ``fl.gather``,
+Device stages: in the cohort round programs ``fl.gather``,
 ``fl.local_sgd``, ``fl.wire``, ``fl.upload``, ``fl.w_refresh``,
-``fl.mix``.
+``fl.mix``; in ucfl's init programs ``fl.init.gradients``,
+``fl.init.gram`` and ``fl.init.weights``, the names of the host spans
+that launch them.
 """
 from __future__ import annotations
 

@@ -119,13 +119,15 @@ def cohort_gather(full, idx, *, impl=None):
     return cohort_rows.gather_rows(full, idx)
 
 
-def gram(g, *, impl=None, block_d=None):
-    """Gram matrix ``g g^T`` of (m, d) rows -> (m, m) f32."""
+def gram(g, *, impl=None, block_d=None, stage=None):
+    """Gram matrix ``g g^T`` of (m, d) rows -> (m, m) f32. ``stage`` names
+    the kernel program's device stage."""
     impl, _ = _split_variant(_auto_impl(impl))
     if impl == "ref":
         return ref.gram(g)
     kwargs = {} if block_d is None else {"block_d": block_d}
-    return gram_pallas(g, interpret=(impl == "interpret"), **kwargs)
+    return gram_pallas(g, interpret=(impl == "interpret"), stage=stage,
+                       **kwargs)
 
 
 def delta_from_gram(gr):

@@ -313,6 +313,43 @@ def test_pairwise_delta_matches_oracle(m, d, dtype):
                                rtol=2e-2, atol=tol)
 
 
+@pytest.mark.parametrize("m", [20, 300])
+@pytest.mark.parametrize("d", [1000, 5000])
+def test_gram_tiled_matches_oracle(m, d):
+    """The smallest output tiles (128: a block_m under it rounds up), so
+    that m=20 takes the two-input path with one ragged tile and m=300
+    takes 3 x 3 tiles with ragged edges; d blocks of 256 with a ragged
+    last block."""
+    from repro.kernels.pairwise_delta import gram_pallas, tiles
+
+    tile_m, tile_d, inputs = tiles(m, d, block_m=8, block_d=256)
+    assert (tile_m, tile_d, inputs) == (128, 256, 2)
+    assert m % tile_m and d % tile_d
+    rng = np.random.default_rng(m + d)
+    g = jnp.asarray(rng.normal(size=(m, d)).astype(np.float32))
+    got = np.asarray(gram_pallas(g, block_m=8, block_d=256,
+                                 interpret=True))
+    want = np.asarray(ref.gram(g))
+    # a float32 dot product's round-off scales with sum |g_ik g_jk|, which
+    # the diagonal bounds, not with the (possibly tiny) entry itself
+    np.testing.assert_allclose(got, want, rtol=1e-5,
+                               atol=1e-5 * float(np.max(np.diag(want))))
+
+
+def test_gram_tiles_keep_one_block_until_the_tile_is_full():
+    """The paper shape is one output block through one input, as before
+    the tiling; FEMNIST's 3,550 clients take 7 x 7 tiles of 512 within
+    the VMEM budget."""
+    from repro.kernels.masked_mix_scatter import _VMEM_BUDGET_FLOATS
+    from repro.kernels.pairwise_delta import tiles
+
+    assert tiles(20, 45276) == (24, 4096, 1)
+    tile_m, tile_d, inputs = tiles(3550, 48846)
+    assert (tile_m, inputs) == (512, 2) and -(-3550 // tile_m) == 7
+    assert (2 * inputs * tile_m * tile_d + 2 * tile_m * tile_m
+            <= _VMEM_BUDGET_FLOATS)
+
+
 @pytest.mark.parametrize("m,k,f", [(4, 2, 4), (20, 4, 20), (100, 7, 100),
                                    (9, 3, 17)])
 def test_kmeans_assign_matches_oracle(m, k, f):

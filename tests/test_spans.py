@@ -1,7 +1,8 @@
 """The program's own tracing: the ``fl.`` host spans that ``simulation.run``
 and the ucfl init open, and the ``fl.`` stage scopes of the cohort round
-programs (``repro.federated.spans``), read back the way the benchmark's
-trace reducer reads them (``bench/stages.py``)."""
+programs and of ucfl's init programs (``repro.federated.spans``), read
+back the way the benchmark's trace reducer reads them
+(``bench/stages.py``)."""
 import functools
 import glob
 import os
@@ -145,3 +146,31 @@ def test_round_program_carries_stage_scopes(variant):
     ).lower(state, key).as_text(debug_info=True)
     found = set(re.findall(r'[/"](fl\.[a-z_]+)/', text))
     assert found == {spans.GATHER, spans.LOCAL_SGD, spans.MIX, *extra}
+
+
+def _init_lowering(stage):
+    """The lowered text of the init program that runs under ``stage``."""
+    from repro.core import ucfl
+    from repro.kernels import ops
+
+    data, params0 = _setup()
+    if stage == spans.INIT_GRADIENTS:
+        return ucfl._special_round_uploads.lower(
+            params0, data.x, data.y, apply_fn=lenet.apply, var_batch_size=20,
+            chunk_size=4, mesh=None).as_text(debug_info=True)
+    g = np.ones((6, 300), np.float32)
+    if stage == spans.INIT_GRAM:
+        return jax.jit(lambda g: ops.gram(g, impl="interpret", stage=stage)
+                       ).lower(g).as_text(debug_info=True)
+    return ucfl._delta_and_weights.lower(
+        np.eye(6, dtype=np.float32), np.ones(6, np.float32),
+        data.n).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("stage", [spans.INIT_GRADIENTS, spans.INIT_GRAM,
+                                   spans.INIT_WEIGHTS])
+def test_init_programs_carry_stage_scopes(stage):
+    """Each of ucfl's init programs carries the device stage of the host
+    span that launches it, and no other ``fl.`` stage."""
+    text = _init_lowering(stage)
+    assert set(re.findall(r'[/"](fl\.[a-z_.]+)/', text)) == {stage}

@@ -5,7 +5,8 @@ and compiles each kernel for a chip that is described, not attached. It
 refuses what interpret mode accepts: slices not aligned to the (8, 128)
 tiling, and kernels whose buffers overflow the scoped VMEM limit. The
 shapes are the paper-scale round (m=20 clients, LeNet at 28x28 with 20
-classes: d=45,312 lane-aligned) and the large-m regime (m=16,384, c=64).
+classes: d=45,312 lane-aligned), the large-m regime (m=16,384, c=64) and
+FEMNIST's Eq. 9 init (m=3,550, 62 classes: d=48,846).
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, so describing it while test modules are
@@ -21,6 +22,7 @@ from repro.kernels.masked_mix_scatter import slab_fits
 
 D = 45312  # LeNet 28x28, 20 classes, aligned to 128 lanes
 M_LARGE, C_LARGE = 16384, 64
+M_FEMNIST, D_FEMNIST = 3550, 48846  # LEAF FEMNIST writers; LeNet, 62 classes
 F32, I32 = jnp.float32, jnp.int32
 
 
@@ -111,11 +113,39 @@ def test_auto_variant_switches_to_hbm_past_slab_fits():
     assert slab_fits(20, 10) and not slab_fits(M_LARGE, C_LARGE)
 
 
-@pytest.mark.parametrize("m", [20, 1024])
-def test_gram_kernel_compiles(spec, m):
+@pytest.mark.parametrize("m,d", [(20, D), (1024, D), (M_FEMNIST, D_FEMNIST),
+                                 (20, 45276)],
+                         ids=["20", "1024", "3550", "20-unaligned"])
+def test_gram_kernel_compiles(spec, m, d):
+    """Up to FEMNIST's 3,550 clients, whose (3,552)^2 f32 Gram is more
+    than the scoped VMEM as one block: the output is tiled."""
     compiled = _compile(lambda g: ops.pairwise_delta(g, impl="pallas"),
-                        spec((m, D)))
+                        spec((m, d)))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_special_round_fits_at_femnist_scale(spec):
+    """The Eq. 9 gradient program at FEMNIST scale (3,550 writers of 204
+    samples, Eq. 10 minibatch 50, 62 classes) with the derived client
+    chunk: arguments, output and temporaries under 12 GB of the chip's 16.
+    Unchunked, one conv1 activation buffer alone needs 25 GB."""
+    from repro.core import ucfl
+    from repro.models import lenet
+
+    n, vb = 204, 50
+    chunk = ucfl.init_chunk(M_FEMNIST, n, vb)
+    assert chunk == 50
+    params0 = lenet.init(jax.random.PRNGKey(0), input_hw=(28, 28),
+                         channels=1, num_classes=62)
+    compiled = ucfl._special_round_uploads.lower(
+        jax.tree.map(lambda a: spec(a.shape, a.dtype), params0),
+        spec((M_FEMNIST, n, 28, 28, 1)), spec((M_FEMNIST, n), I32),
+        apply_fn=lenet.apply, var_batch_size=vb, chunk_size=chunk,
+        mesh=None).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < 12e9, total
 
 
 @pytest.mark.parametrize("k,m", [(2, 20), (2, 1024)])

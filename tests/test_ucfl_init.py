@@ -72,3 +72,42 @@ def test_second_init_compiles_nothing():
     finally:
         jax.monitoring.unregister_event_duration_listener(on_duration)
     assert compiled == []
+
+
+def test_init_chunk_is_derived_from_the_data():
+    """Without a chunk the paper cell's 20 clients of 10 Eq. 10
+    minibatches of 50 run at once; FEMNIST's 3,550 writers of 4 run in 71
+    chunks of 50 (10,000 samples each, the paper's whole batch); a prime
+    count takes the budget's chunk with a padded last one; an explicit
+    ``chunk_size`` wins."""
+    assert ucfl.init_chunk(20, 500, 50) == 20
+    assert ucfl.init_chunk(3550, 204, 50) == 50
+    assert ucfl.init_chunk(101, 204, 50) == 50
+    assert ucfl.init_chunk(3550, 204, 50, chunk_size=5) == 5
+    assert ucfl.init_chunk(20, 500, 50, chunk_size=3) == 3
+
+
+def test_collaboration_runs_the_derived_chunk(monkeypatch):
+    """compute_collaboration hands the gradient program the derived chunk
+    (here 3 of 7 clients, a padded last chunk), and the chunked pass gives
+    the unchunked gradients."""
+    data, params0 = _setup()
+    run = functools.partial(ucfl.compute_collaboration, lenet.apply, params0,
+                            data, var_batch_size=20)
+    whole = run()
+    monkeypatch.setattr(ucfl, "INIT_CHUNK_SAMPLES", 3 * 60)
+    assert ucfl.init_chunk(7, 60, 20) == 3
+    seen = []
+    inner = ucfl._special_round_uploads
+
+    def spy(*args, **kwargs):
+        seen.append(kwargs["chunk_size"])
+        return inner(*args, **kwargs)
+
+    monkeypatch.setattr(ucfl, "_special_round_uploads", spy)
+    chunked = run()
+    assert seen == [3]
+    for key in ("full_grads", "sigma_sq", "W"):
+        np.testing.assert_allclose(np.asarray(chunked[key]),
+                                   np.asarray(whole[key]),
+                                   rtol=1e-5, atol=1e-6, err_msg=key)
